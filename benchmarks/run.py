@@ -51,6 +51,7 @@ from repro.core.distributed import DistributedGraphContext, build_partition_plan
 from repro.filters import GraphFilter, get_backend
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.launch.compile_cache import use_compile_cache
 from repro.solvers import (
     GramProblem,
     LassoProblem,
@@ -210,6 +211,8 @@ def _train_worker(full: bool) -> dict:
         env = dict(os.environ)
         env["PYTHONPATH"] = str(script.parent.parent / "src")
         env.pop("XLA_FLAGS", None)  # worker forces its own device count
+        # A CPU-only tool: this process may hold the accelerator already.
+        env["JAX_PLATFORMS"] = "cpu"
         cmd = [sys.executable, str(script)] + (["--full"] if full else [])
         proc = subprocess.run(cmd, env=env, capture_output=True, text=True,
                               timeout=1800, check=True)
@@ -862,6 +865,7 @@ def main() -> None:
                     help="suffix for the BENCH_<tag>.json perf record "
                          "(committed records track the trajectory per PR)")
     args = ap.parse_args()
+    use_compile_cache()
     print("name,us_per_call,derived")
     for bench in BENCHES:
         if args.only and args.only not in bench.__name__:

@@ -1,49 +1,19 @@
-"""Small version-compatibility shims for jax API moves.
-
-The repo targets the jax series where ``shard_map`` and the Pallas TPU
-compiler-params type were promoted/renamed; these aliases keep one code
-path across versions without scattering try/except at call sites.
-"""
+"""The mesh helpers every distributed module imports: ``shard_map`` and a
+``make_mesh`` whose axes are all Auto."""
 
 from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5: promoted to the top-level namespace
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x: translate the modern kwargs
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map(f, *, mesh, in_specs, out_specs,
-                  axis_names=None, check_vma=None):
-        """New-API ``shard_map`` on old jax.
-
-        ``axis_names={...}`` (manual axes) becomes the old ``auto=`` (its
-        complement over the mesh); ``check_vma`` was called ``check_rep``.
-        """
-        kwargs = {}
-        if axis_names is not None:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map_04(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types on every version.
-
-    Newer jax requires ``axis_types=(AxisType.Auto, ...)`` to opt out of
-    explicit sharding; older jax has no ``AxisType`` at all (Auto is the
-    only behaviour). This wrapper requests Auto where the argument exists.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types (opting out of explicit
+    sharding on every axis)."""
     return jax.make_mesh(
         axis_shapes, axis_names,
-        axis_types=(axis_type.Auto,) * len(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
     )
 
 

@@ -379,15 +379,17 @@ class GraphFilter:
             self._states[key] = be.prepare(self, **opts)
         return self._states[key]
 
-    def prepare_backend(self, backend: str = "dense", **opts) -> None:
-        """Eagerly build (and cache) ``backend``'s prepared state.
+    def prepare_backend(self, backend: str = "dense", **opts) -> Any:
+        """Eagerly build (and cache) ``backend``'s prepared state, and
+        return it (e.g. the Block-ELL operands of ``bsr``, the partition
+        plan of ``halo``).
 
         Normally preparation happens lazily on the first apply; callers
         staging a trace (``jax.jit`` over a filter call) use this so the
         prepared operands are concrete before tracing begins.
         """
         be = self._backend(backend)
-        self._backend_state(be, opts)
+        return self._backend_state(be, opts)
 
     def apply(self, f: jax.Array, *, backend: str = "dense", **opts) -> jax.Array:
         """Apply the union ``Phi~ f`` through one shared recurrence.

@@ -251,7 +251,7 @@ class BsrBackend:
     stepwise kernel.
 
     Options: ``block_size`` (prepare; default 8), ``interpret`` (default:
-    auto — True off-TPU), ``f_tile`` / ``fuse`` overrides, and
+    True on the CPU backend only), ``f_tile`` / ``fuse`` overrides, and
     ``krylov_dtype`` (apply; default f32 — ``"bfloat16"`` halves the
     kernels' Krylov working set while all combines stay f32, widening
     the fused-kernel regime in ``autotune.select_tiling``).
@@ -314,7 +314,10 @@ class BsrBackend:
     ):
         c = _coeffs_or(filt, coeffs)
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            # Interpret only on the CPU (tests, examples). Elsewhere the
+            # kernel compiles for the device or raises: never a silent
+            # interpreter run on an accelerator.
+            interpret = jax.default_backend() == "cpu"
         kd = jnp.dtype(krylov_dtype or jnp.float32).name
         fp, squeeze = self._forward(state, f)
         if isinstance(state, _BsrMultiState):

@@ -2,9 +2,9 @@
 
 Real autotuning (sweep + timing) is wasteful for a filter that is built
 once and applied millions of times with a handful of distinct shapes.
-Instead we keep a small table of measured-good configurations keyed by
-coarse shape buckets, and fall back to a deterministic VMEM-budget model
-for shapes the table does not cover (DESIGN.md Sec. 6.3).
+Instead we keep a small table of preferred tiles keyed by coarse shape
+buckets, and a deterministic VMEM-budget model decides whether the fused
+kernel fits (DESIGN.md Sec. 6.3).
 
 The decision this module makes:
 
@@ -19,20 +19,24 @@ The decision this module makes:
 from __future__ import annotations
 
 import dataclasses
+import math
 
 import jax.numpy as jnp
 
 __all__ = ["Tiling", "select_tiling", "union_vmem_bytes"]
 
-# ~16 MB/core on current TPUs; leave headroom for pipelining buffers and
-# the compiler's own scratch. Interpret mode has no real budget but we keep
-# the same decisions so CPU tests exercise the TPU code paths.
+# Bytes the fused working set may take of the compiler's scoped VMEM
+# limit (16 MiB on v5e), leaving room for the compiler's own scratch.
+# Interpret mode has no real budget but we keep the same decisions so CPU
+# tests exercise the TPU code paths. ``tests/test_tpu_compile.py`` compiles
+# a fused shape near this budget for v5e.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
-# Measured-good f_tile per (block_size bucket, dtype bucket). The table is
-# deliberately tiny: MXU-aligned 128 everywhere F allows it, smaller lanes
-# only for small-F workloads. Extend with measured entries as new shapes
-# ship; unknown keys fall through to the formula below.
+# Preferred f_tile per (block_size bucket, dtype bucket), widest first:
+# MXU-aligned 128 everywhere F allows it. No entry has been timed on a
+# chip yet. Entries that are neither a multiple of 128 nor F itself are
+# never used: Pallas TPU refuses a block whose last dim is neither (see
+# ``_legal_f_tiles``). Unknown keys fall through to the default ladder.
 _F_TILE_TABLE: dict[tuple[int, str], tuple[int, ...]] = {
     (8, "float32"): (128, 64, 32, 16, 8),
     (8, "bfloat16"): (128, 64, 32, 16),
@@ -41,6 +45,7 @@ _F_TILE_TABLE: dict[tuple[int, str], tuple[int, ...]] = {
     (128, "float32"): (256, 128),
     (128, "bfloat16"): (256, 128),
 }
+_DEFAULT_LADDER = (256, 128, 64, 32, 16, 8)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +63,25 @@ class Tiling:
     vmem_bytes: int
 
 
+def _vmem_array_bytes(shape, dtype) -> int:
+    """Bytes of an array in VMEM: the last dim pads to 128 lanes and the
+    second-to-last to the dtype's sublane tile (8 rows of 32-bit words,
+    16 of bf16), as Mosaic lays them out."""
+    itemsize = jnp.dtype(dtype).itemsize
+    *lead, rows, lanes = shape
+    sublanes = 8 * (4 // itemsize)
+    rows = -(-rows // sublanes) * sublanes
+    lanes = -(-lanes // 128) * 128
+    return math.prod(lead) * rows * lanes * itemsize
+
+
+def _legal_f_tiles(f: int, preferred=_DEFAULT_LADDER) -> list[int]:
+    """F tiles the TPU lowering accepts, widest first: divisors of F that
+    are multiples of 128, or F itself (a block spanning the whole dim)."""
+    tiles = [c for c in preferred if f % c == 0 and (c % 128 == 0 or c == f)]
+    return tiles or [f]
+
+
 def union_vmem_bytes(
     n: int,
     f_tile: int,
@@ -71,19 +95,25 @@ def union_vmem_bytes(
 ) -> int:
     """VMEM working set of the fused union kernel (bytes).
 
-    Counts the resident Laplacian tiles, the input tile, two Krylov
-    (ping/pong) buffers in ``krylov_dtype``, the (eta, N, f_tile) f32
-    accumulators, and the output tile. ``krylov_dtype="bfloat16"`` halves
-    the Krylov term, which is why the bf16 mode raises the fuse threshold
-    in :func:`select_tiling`.
+    Counts each array at its padded VMEM layout (``_vmem_array_bytes``).
+    The pipelined operands — the resident Laplacian tiles, the input
+    tile and the (eta, N, f_tile) output tile — are double-buffered by
+    Pallas, so they count twice; the scratch counts once: two Krylov
+    (ping/pong) buffers in ``krylov_dtype`` and the (eta, N, f_tile) f32
+    accumulators. ``krylov_dtype="bfloat16"`` halves the Krylov term,
+    which is why the bf16 mode raises the fuse threshold in
+    :func:`select_tiling`.
     """
-    itemsize = jnp.dtype(dtype).itemsize
-    blocks_b = n_rows * k_max * block * block * itemsize
-    sig_b = n * f_tile * itemsize  # input tile
-    krylov_b = 2 * n * f_tile * jnp.dtype(krylov_dtype).itemsize  # ping/pong
-    acc_b = eta * n * f_tile * 4  # f32 accumulators
-    out_b = eta * n * f_tile * itemsize
-    return blocks_b + sig_b + krylov_b + acc_b + out_b
+    pipelined = (
+        _vmem_array_bytes((n_rows, k_max, block, block), dtype)
+        + _vmem_array_bytes((n, f_tile), dtype)
+        + _vmem_array_bytes((eta, n, f_tile), dtype)
+    )
+    scratch = (
+        2 * _vmem_array_bytes((n, f_tile), krylov_dtype)
+        + _vmem_array_bytes((eta, n, f_tile), jnp.float32)
+    )
+    return 2 * pipelined + scratch
 
 
 def select_tiling(
@@ -119,39 +149,23 @@ def select_tiling(
     Returns
     -------
     Tiling
-        Largest table-listed ``f_tile`` dividing F (falling back to the
-        largest power-of-two divisor of F up to 128), with ``fuse`` set
-        when the fused working set fits the budget.
+        The widest legal ``f_tile`` (``_legal_f_tiles`` over the table's
+        preferences) whose fused working set fits the budget, with
+        ``fuse=True``; else the widest legal tile with ``fuse=False``.
     """
     dt_name = jnp.dtype(dtype).name
-    candidates = _F_TILE_TABLE.get(
-        (block, dt_name), (256, 128, 64, 32, 16, 8)
-    )
-    f_tile = next((c for c in candidates if f % c == 0), None)
-    if f_tile is None:
-        f_tile = 1
-        c = 1
-        while c <= min(f, 128):
-            if f % c == 0:
-                f_tile = c
-            c *= 2
-
-    # Shrink the tile further if that is what it takes to fuse.
-    best = None
-    for cand in sorted({c for c in (f_tile, *candidates) if f % c == 0},
-                       reverse=True):
+    tiles = _legal_f_tiles(f, _F_TILE_TABLE.get((block, dt_name), _DEFAULT_LADDER))
+    # Widest legal tile that fuses; the widest one when none does.
+    for cand in tiles:
         bytes_ = union_vmem_bytes(n, cand, eta, n_rows, k_max, block, dtype,
                                   krylov_dtype=krylov_dtype)
         if bytes_ <= vmem_budget:
-            best = Tiling(f_tile=cand, fuse=True, vmem_bytes=bytes_)
-            break
-    if best is None:
-        best = Tiling(
-            f_tile=f_tile,
-            fuse=False,
-            vmem_bytes=union_vmem_bytes(
-                n, f_tile, eta, n_rows, k_max, block, dtype,
-                krylov_dtype=krylov_dtype,
-            ),
-        )
-    return best
+            return Tiling(f_tile=cand, fuse=True, vmem_bytes=bytes_)
+    return Tiling(
+        f_tile=tiles[0],
+        fuse=False,
+        vmem_bytes=union_vmem_bytes(
+            n, tiles[0], eta, n_rows, k_max, block, dtype,
+            krylov_dtype=krylov_dtype,
+        ),
+    )

@@ -13,7 +13,10 @@ replaced by Block-ELL — spatially-ordered vertices give few dense
 contraction against an ``F``-wide signal batch. The data-dependent tile
 gather uses **scalar prefetch**: block-column indices live in SMEM and feed
 the BlockSpec index_map, so Pallas pipelines the HBM->VMEM tile streams
-without kernel-visible gathers.
+without kernel-visible gathers. The indices are prefetched flat,
+``(n_rows * k_max,)``: SMEM pads the last dim of a 2-D operand to 128
+words, so a 2-D ``(n_rows, k_max)`` table would cost 512 B per block-row
+whatever ``k_max`` is, and 2048 block-rows would fill the whole 1 MiB.
 
 Grid: ``(F_tiles, n_block_rows, k_max)`` with the sparse-column loop
 innermost — the output block revisits k_max times and accumulates in VMEM
@@ -29,17 +32,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams across 0.4.x/0.5.x.
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 __all__ = ["cheb_step_pallas", "cheb_union_pallas"]
+
+# Tile contractions run at full f32 precision (see _cheb_step_kernel).
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _cheb_step_kernel(
     # scalar-prefetch operands
-    cols_ref,  # (n_rows, k_max) int32, SMEM
+    cols_ref,  # (n_rows * k_max,) int32, SMEM
     # tensor operands
     blocks_ref,  # (1, 1, B, B)    Laplacian tile for (i, j)
     t1g_ref,  # (B, FT)            gathered T_{k-1}[cols[i, j]]
@@ -61,10 +62,13 @@ def _cheb_step_kernel(
 
     # MXU contraction for this Laplacian tile; accumulate L @ t1 in f32
     # VMEM scratch (bf16 inputs still accumulate at full precision).
+    # HIGHEST: Mosaic's default f32 contraction rounds the operands to
+    # bf16 (4e-3 relative error per apply on a v5e).
     acc_ref[...] += jnp.dot(
         blocks_ref[0, 0].astype(jnp.float32),
         t1g_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
+        precision=_F32,
     )
 
     @pl.when(j == k_max - 1)
@@ -135,7 +139,7 @@ def cheb_step_pallas(
                     (1, 1, b, b), lambda fi, i, j, cols: (i, j, 0, 0)
                 ),
                 pl.BlockSpec(  # gathered t1 rows via scalar-prefetched cols
-                    (b, ft), lambda fi, i, j, cols: (cols[i, j], fi)
+                    (b, ft), lambda fi, i, j, cols: (cols[i * k_max + j], fi)
                 ),
                 pl.BlockSpec((b, ft), lambda fi, i, j, cols: (i, fi)),
                 pl.BlockSpec((b, ft), lambda fi, i, j, cols: (i, fi)),
@@ -144,11 +148,11 @@ def cheb_step_pallas(
             scratch_shapes=[pltpu.VMEM((b, ft), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n, f), t1.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(cols, blocks, t1, t1, t2)
+    )(cols.reshape(-1), blocks, t1, t1, t2)
 
 
 # ---------------------------------------------------------------------------
@@ -158,7 +162,7 @@ def cheb_step_pallas(
 
 def _cheb_union_kernel(
     # scalar-prefetch operand
-    cols_ref,  # (n_rows, k_max) int32, SMEM
+    cols_ref,  # (n_rows * k_max,) int32, SMEM
     # tensor operands
     blocks_ref,  # (n_rows, k_max, B, B) — the whole Block-ELL Laplacian
     f_ref,  # (N, FT)                     input signal tile (= T_0)
@@ -199,11 +203,11 @@ def _cheb_union_kernel(
         """(L @ src)[i-th block row] via scalar-prefetched tile gather."""
         acc = jnp.zeros((block, ft), f32)
         for j in range(k_max):
-            c = cols_ref[i, j]
+            c = cols_ref[i * k_max + j]
             seg = src_ref[pl.ds(c * block, block), :]
             acc += jnp.dot(
                 blocks_ref[i, j].astype(f32), seg.astype(f32),
-                preferred_element_type=f32,
+                preferred_element_type=f32, precision=_F32,
             )
         return acc
 
@@ -347,8 +351,8 @@ def cheb_union_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((eta, n, fdim), f.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(cols, blocks, f)
+    )(cols.reshape(-1), blocks, f)

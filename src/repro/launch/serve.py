@@ -37,13 +37,17 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.dryrun:
+        import os
         import subprocess
         import sys
         cmd = [sys.executable, "-m", "repro.launch.dryrun",
                "--arch", args.arch, "--shape", args.shape]
         if args.multi_pod:
             cmd.append("--multi-pod")
-        raise SystemExit(subprocess.call(cmd))
+        # The dry-run forces 512 host devices: keep it off the
+        # accelerator this process may already hold.
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        raise SystemExit(subprocess.call(cmd, env=env))
 
     cfg = registry.get_smoke(args.arch) if args.smoke \
         else registry.get(args.arch)

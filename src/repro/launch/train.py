@@ -71,7 +71,10 @@ def main() -> None:
                "--arch", args.arch, "--shape", args.shape]
         if args.multi_pod:
             cmd.append("--multi-pod")
-        raise SystemExit(subprocess.call(cmd))
+        # The dry-run forces 512 host devices: keep it off the
+        # accelerator this process may already hold.
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        raise SystemExit(subprocess.call(cmd, env=env))
 
     cfg = registry.get_smoke(args.arch) if args.smoke else registry.get(args.arch)
     par = ParallelConfig(attn_impl="naive", remat="none",

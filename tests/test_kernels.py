@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from repro.core import chebyshev, graph, multipliers
-from repro.kernels import ops, ref
+from repro.kernels import autotune, ops, ref
 from repro.kernels.cheb_bsr import cheb_step_pallas
 
 
@@ -63,6 +63,20 @@ def test_cheb_step_matches_ref(block, f, ftile, dtype):
         np.testing.assert_allclose(
             np.asarray(got, np.float64), np.asarray(want, np.float64),
             rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("f", [8, 128, 256])
+@pytest.mark.parametrize("key", sorted(autotune._F_TILE_TABLE))
+def test_select_tiling_returns_lowerable_f_tile(key, f):
+    """Pallas TPU takes a block whose last dim is a multiple of 128 or the
+    whole dim: every f_tile select_tiling answers, fused or not, is one."""
+    block, dtype = key
+    for n in (256, 4096, 16384):
+        for krylov in (jnp.float32, jnp.bfloat16):
+            t = autotune.select_tiling(n, f, 5, n // block, 8, block, dtype,
+                                       krylov_dtype=krylov)
+            assert f % t.f_tile == 0, (n, krylov, t)
+            assert t.f_tile % 128 == 0 or t.f_tile == f, (n, krylov, t)
 
 
 def test_full_apply_matches_dense_oracle():

@@ -128,3 +128,16 @@ def test_serve_launcher_cli():
         capture_output=True, text=True, env=env, timeout=600, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-1500:]
     assert "tokens_per_s" in proc.stdout
+
+
+def test_chip_smoke_refuses_cpu():
+    """``chip_smoke.py`` has no CPU fallback: on the CPU it fails at its
+    device check, before the field is built, and prints no result line."""
+    env = {"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"}
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")],
+        capture_output=True, text=True, env=env, timeout=60, cwd=REPO)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert "phase=field" not in proc.stdout
+    assert "no TPU" in proc.stderr

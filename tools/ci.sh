@@ -2,8 +2,8 @@
 # CI entry point: install dev deps, lint, run the test suite on CPU, and
 # smoke-run the quickstart example so example drift is caught.
 #
-# All Pallas paths run with interpret=True off-TPU (the backends choose it
-# automatically), so the whole matrix — including the fused union-combine
+# All Pallas paths run with interpret=True on the CPU (the bsr backend
+# chooses it there and nowhere else), so the whole matrix — including the fused union-combine
 # kernel and the multi-device subprocess tests (forced host devices) — is
 # exercised on a plain CPU runner. Collection errors fail the run
 # (pytest exits non-zero on them; --co smoke-checks first for clarity).
