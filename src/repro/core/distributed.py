@@ -47,6 +47,7 @@ from repro.core.compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import graph as graph_lib
 
 __all__ = ["PartitionPlan", "build_partition_plan",
@@ -278,10 +279,11 @@ def build_partition_plan(
     adjacency, coords, n_parts: int, dtype=jnp.float32
 ) -> PartitionPlan:
     """Partition a graph spatially and precompute halo-exchange tables."""
-    order, boundary_counts, n_local = _partition_layout(
-        adjacency, coords, n_parts)
-    return _plan_tables(
-        adjacency, order, boundary_counts, n_parts, n_local, dtype)
+    with obs.span("halo.plan", n=int(adjacency.shape[0]), n_parts=n_parts):
+        order, boundary_counts, n_local = _partition_layout(
+            adjacency, coords, n_parts)
+        return _plan_tables(
+            adjacency, order, boundary_counts, n_parts, n_local, dtype)
 
 
 def build_shift_partition_plans(

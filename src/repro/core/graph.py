@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "SensorGraph",
     "gaussian_kernel_weights",
@@ -61,7 +63,8 @@ class SensorGraph:
         return int(np.count_nonzero(np.asarray(self.adjacency)) // 2)
 
     def laplacian(self) -> jax.Array:
-        return laplacian(self.adjacency)
+        with obs.span("graph.laplacian", n=self.n_vertices):
+            return laplacian(self.adjacency)
 
     def lmax_bound(self) -> jax.Array:
         return lmax_upper_bound(self.adjacency)
@@ -329,4 +332,5 @@ def spatial_partition_order(coords, n_parts: int) -> np.ndarray:
         cut = len(ids) * left // parts
         return np.concatenate([rec(order[:cut], left), rec(order[cut:], parts - left)])
 
-    return rec(np.arange(n), n_parts)
+    with obs.span("graph.spatial_order", n=n):
+        return rec(np.arange(n), n_parts)

@@ -41,13 +41,13 @@ churn first-class:
 from __future__ import annotations
 
 import dataclasses
-from collections import Counter
 from typing import Iterable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import chebyshev
 from repro.core.graph import SensorGraph, lmax_power_iteration
 
@@ -249,17 +249,19 @@ class LmaxTracker:
 # ---------------------------------------------------------------------------
 # Churn kernels. Module-level jits so the compile cache is keyed purely by
 # bucket shapes: any frame whose reach pads to an already-seen power-of-two
-# bucket reuses the compiled program. The trace counter increments only when
-# jit actually (re)traces — the Python body runs at trace time only — which
-# is what the steady-state-zero-recompiles pin measures.
+# bucket reuses the compiled program. ``repro.obs`` counts each jaxpr trace
+# JAX reports, by function name — what the steady-state-zero-recompiles pin
+# measures.
 # ---------------------------------------------------------------------------
 
-_KERNEL_TRACES: Counter = Counter()
+_CHURN_KERNELS = (
+    "churn_correction", "restricted_cheb_apply_krylov", "dense_cheb_apply_krylov")
 
 
 def kernel_trace_counts() -> dict[str, int]:
     """Snapshot of per-kernel trace counts (compilations) so far."""
-    return dict(_KERNEL_TRACES)
+    traces = obs.compile_counts("trace")
+    return {k: traces[k] for k in _CHURN_KERNELS if traces.get(k)}
 
 
 @jax.jit
@@ -282,7 +284,6 @@ def churn_correction(lap_new_sub, dlap_sub, tk_sub, coeffs, lmax):
       (M+1, b, F) difference stack (add it to the stored Krylov stack to
       re-anchor it on ``L'``).
     """
-    _KERNEL_TRACES["churn_correction"] += 1
     coeffs = jnp.asarray(coeffs, tk_sub.dtype)
     alpha = jnp.asarray(lmax, tk_sub.dtype) / 2.0
     d0 = jnp.zeros_like(tk_sub[0])
@@ -316,7 +317,6 @@ def churn_correction(lap_new_sub, dlap_sub, tk_sub, coeffs, lmax):
 def restricted_cheb_apply_krylov(lap_sub, d_sub, coeffs, lmax):
     """Signal-delta filtering on an induced submatrix, keeping the Krylov
     difference stack so the stored ``t_k`` can be updated too."""
-    _KERNEL_TRACES["restricted_cheb_apply_krylov"] += 1
     return chebyshev.cheb_apply_krylov(lambda v: lap_sub @ v, d_sub, coeffs, lmax)
 
 
@@ -324,5 +324,4 @@ def restricted_cheb_apply_krylov(lap_sub, d_sub, coeffs, lmax):
 def dense_cheb_apply_krylov(lap, f, coeffs, lmax):
     """Full dense refilter that captures the Krylov stack — the churn
     path's activation / fallback frame."""
-    _KERNEL_TRACES["dense_cheb_apply_krylov"] += 1
     return chebyshev.cheb_apply_krylov(lambda v: lap @ v, f, coeffs, lmax)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import chebyshev
 from repro.core.graph import SensorGraph
 from repro.filters import registry
@@ -376,7 +377,8 @@ class GraphFilter:
             sorted((k, v) for k, v in opts.items() if k in be.prepare_opts)
         )
         if key not in self._states:
-            self._states[key] = be.prepare(self, **opts)
+            with obs.span("filter.prepare", backend=be.name):
+                self._states[key] = be.prepare(self, **opts)
         return self._states[key]
 
     def prepare_backend(self, backend: str = "dense", **opts) -> Any:
@@ -485,12 +487,12 @@ class GraphFilter:
         state = self._backend_state(be, opts)
         c = coeffs
 
-        def run(panel: jax.Array) -> jax.Array:
+        def panel_apply(panel: jax.Array) -> jax.Array:
             return be.apply(self, state, panel, coeffs=c, **opts)
 
         if be.capabilities.traceable:
-            return jax.jit(run, donate_argnums=(0,) if donate else ())
-        return run
+            return jax.jit(panel_apply, donate_argnums=(0,) if donate else ())
+        return panel_apply
 
     def apply_sparse(
         self,

@@ -27,6 +27,7 @@ by ``tests/test_filters.py``).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 
 import jax
@@ -37,6 +38,7 @@ from repro.core.compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import chebyshev
 from repro.core import graph as graph_lib
 from repro.core.distributed import (
@@ -287,7 +289,8 @@ class BsrBackend:
                 bells=bells, perm=perm, inv=inv, n=n, n_pad=bells[0].n
             )
         lap = np.asarray(g.laplacian(), np.float64)
-        bell = kref.bsr_from_dense(lap[np.ix_(perm, perm)], block_size)
+        with obs.span("bsr.block_ell", n=n, block_size=block_size):
+            bell = kref.bsr_from_dense(lap[np.ix_(perm, perm)], block_size)
         return _BsrState(bell=bell, perm=perm, inv=inv, n=n, n_pad=bell.n)
 
     def _forward(self, state: _BsrState, f):
@@ -319,7 +322,8 @@ class BsrBackend:
             # interpreter run on an accelerator.
             interpret = jax.default_backend() == "cpu"
         kd = jnp.dtype(krylov_dtype or jnp.float32).name
-        fp, squeeze = self._forward(state, f)
+        with jax.named_scope("repro.bsr.forward_perm"):
+            fp, squeeze = self._forward(state, f)
         if isinstance(state, _BsrMultiState):
             # Joint recurrence over the per-shift Block-ELL matvecs (the
             # jnp reference oracle — the fused/stepwise Pallas kernels are
@@ -352,7 +356,8 @@ class BsrBackend:
                 bell.blocks, bell.cols, fp, jnp.asarray(c, fp.dtype),
                 filt.lmax, interpret=interpret, f_tile=ft, krylov_dtype=kd,
             )
-        out = out[:, state.inv]
+        with jax.named_scope("repro.bsr.inverse_perm"):
+            out = out[:, state.inv]
         return out[:, :, 0] if squeeze else out
 
     @staticmethod
@@ -407,6 +412,7 @@ class _ShardedBackendBase:
     # backends cannot live inside a lax.scan body (traceable=False).
     capabilities = BackendCapabilities()
     prepare_opts: frozenset[str] = frozenset({"mesh", "axis", "n_parts"})
+    _apply_ids = itertools.count()  # the ``apply`` id that ties one apply's spans
 
     def prepare(
         self,
@@ -419,7 +425,8 @@ class _ShardedBackendBase:
     ):
         g = _require_graph(filt, self.name)
         if mesh is None:
-            mesh = _default_mesh(axis, n_parts)
+            with obs.span("halo.context"):
+                mesh = _default_mesh(axis, n_parts)
         if filt.n_shifts > 1:
             # One layout from the union edge pattern, one plan per shift.
             plans = build_shift_partition_plans(
@@ -440,15 +447,25 @@ class _ShardedBackendBase:
         c = _coeffs_or(filt, coeffs)
         f = jnp.asarray(f)
         squeeze = f.ndim == 1
-        sharded = ctx.scatter_signal(f)
-        if isinstance(ctx, MultiShiftGraphContext):
-            # Joint recurrence: per-shift halo exchange inside one
-            # shard_map program (serial exchange->matvec per shift; the
-            # overlapped schedule remains single-shift only).
-            out = ctx.cheb_apply_joint(sharded, c)
-        else:
-            out = ctx.cheb_apply(sharded, c, filt.lmax, backend=self.name, overlap=overlap)
-        out = jnp.asarray(ctx.gather_signal(np.asarray(out)))
+        aid = next(self._apply_ids) if obs.enabled() else None
+        with obs.span("halo.scatter", apply=aid):
+            sharded = ctx.scatter_signal(f)
+        with obs.span("halo.dispatch", apply=aid):
+            if isinstance(ctx, MultiShiftGraphContext):
+                # Joint recurrence: per-shift halo exchange inside one
+                # shard_map program (serial exchange->matvec per shift; the
+                # overlapped schedule remains single-shift only).
+                out = ctx.cheb_apply_joint(sharded, c)
+            else:
+                out = ctx.cheb_apply(sharded, c, filt.lmax, backend=self.name, overlap=overlap)
+        # The host copy below blocks here anyway; waiting first splits the
+        # device's time from the host's.
+        with obs.span("halo.wait", apply=aid):
+            out.block_until_ready()
+        with obs.span("halo.gather", apply=aid):
+            out = ctx.gather_signal(np.asarray(out))
+        with obs.span("halo.put", apply=aid):
+            out = jnp.asarray(out)
         return out[:, :, 0] if squeeze else out
 
     def adjoint(self, filt, ctx, a, **_):

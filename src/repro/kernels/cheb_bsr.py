@@ -152,6 +152,7 @@ def cheb_step_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="cheb_bsr_step",
     )(cols.reshape(-1), blocks, t1, t1, t2)
 
 
@@ -355,4 +356,5 @@ def cheb_union_pallas(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
+        name="cheb_bsr_union",
     )(cols.reshape(-1), blocks, f)

@@ -128,11 +128,13 @@ def cheb_apply_bsr(
     )
 
     t0 = f
-    t1 = step(f, f, first=True)
-    acc = (
-        0.5 * coeffs[:, 0, None, None] * t0[None]
-        + coeffs[:, 1, None, None] * t1[None]
-    )
+    with jax.named_scope("repro.bsr.recurrence"):
+        t1 = step(f, f, first=True)
+    with jax.named_scope("repro.bsr.combine"):
+        acc = (
+            0.5 * coeffs[:, 0, None, None] * t0[None]
+            + coeffs[:, 1, None, None] * t1[None]
+        )
 
     if coeffs.shape[1] <= 2:
         return acc
@@ -141,8 +143,10 @@ def cheb_apply_bsr(
 
     def body(carry, c_k):
         t_prev1, t_prev2, acc = carry
-        t_k = step(t_prev1, t_prev2)
-        acc = acc + c_k[:, None, None] * t_k.astype(acc.dtype)[None]
+        with jax.named_scope("repro.bsr.recurrence"):
+            t_k = step(t_prev1, t_prev2)
+        with jax.named_scope("repro.bsr.combine"):
+            acc = acc + c_k[:, None, None] * t_k.astype(acc.dtype)[None]
         return (t_k, t_prev1, acc), None
 
     (_, _, acc), _ = jax.lax.scan(

@@ -18,7 +18,15 @@ novel shape retriggers a jit trace. This engine is the production story
   (lane, N, bucket) answers every panel in that bucket:
   ``GraphFilter.panel_program`` for applies,
   ``repro.solvers.lasso_panel_program`` for whole fixed-budget solves.
-  ``engine.recompiles`` is exact — steady state is zero.
+  ``engine.recompiles`` counts the cache's misses; on a traceable backend
+  each miss is one trace and one XLA compile of that panel program, and
+  steady state is zero.
+* **Spans** — with :mod:`repro.obs` recording, ``engine.step`` and, per
+  panel, ``engine.pack`` / ``dispatch`` / ``fetch`` / ``unpack`` /
+  ``resolve`` carry the panel's id, which each answered ticket keeps
+  (``Ticket.panel``) with its queue wait (``Ticket.queue_s``: the panel's
+  start, its completion less its run time, less ``t_submit``). With
+  recording off neither id nor wait is computed.
 * **Bounded stream state** — per-stream ``StreamingFilter`` lanes are
   evicted LRU past ``max_streams`` and/or after ``stream_ttl_s`` idle
   seconds (``streams_evicted`` counts them); an evicted stream's next
@@ -42,6 +50,7 @@ from typing import Any, Callable
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.filters import GraphFilter, backend_is_traceable, bucket_size
 from repro.serve.cache import CompiledPanelCache
 from repro.serve.scheduler import Scheduler, SchedulerConfig
@@ -120,6 +129,7 @@ class AsyncGraphFilterEngine:
         self.scheduler = Scheduler(self.config)
         self.cache = CompiledPanelCache()
         self._tids = itertools.count()
+        self._panel_ids = itertools.count()
         # Insertion order doubles as LRU order: touching a stream pops and
         # reinserts it, so the first key is always the coldest lane.
         self._streams: dict[Any, StreamingFilter] = {}
@@ -200,10 +210,11 @@ class AsyncGraphFilterEngine:
         virtual = now is not None
         t = self.clock() if now is None else now
         executed = 0
-        for lane in LANES:
-            while (batch := self.scheduler.ready(lane, t)) is not None:
-                self._execute(lane, batch, t, virtual)
-                executed += 1
+        with obs.span("engine.step"):
+            for lane in LANES:
+                while (batch := self.scheduler.ready(lane, t)) is not None:
+                    self._execute(lane, batch, t, virtual)
+                    executed += 1
         return executed
 
     def drain(self, now: float | None = None) -> int:
@@ -240,19 +251,28 @@ class AsyncGraphFilterEngine:
     # -- panel execution ----------------------------------------------------
 
     def _execute(self, lane, batch, now: float, virtual: bool) -> None:
+        pid = None
+        if obs.enabled():
+            pid = next(self._panel_ids)
+            for req in batch:
+                req.ticket.panel = pid
         t0 = time.perf_counter()
         results = self._run_panel(lane, batch, now)
         dt = time.perf_counter() - t0
         self.busy_s += dt
         if virtual:
-            start = max(now, self._busy_until)
-            t_done = start + dt
+            t_done = max(now, self._busy_until) + dt
             self._busy_until = t_done
         else:
             t_done = self.clock()
-        for req, res in zip(batch, results):
-            req.ticket._resolve(res, t_done)
-            self.scheduler.release(req.ticket)
+        if pid is not None:
+            start = t_done - dt
+            for req in batch:
+                req.ticket.queue_s = start - req.ticket.t_submit
+        with obs.span("engine.resolve", panel=pid):
+            for req, res in zip(batch, results):
+                req.ticket._resolve(res, t_done)
+                self.scheduler.release(req.ticket)
 
     def _run_panel(self, lane, batch, now: float) -> list:
         if lane == "apply":
@@ -275,37 +295,49 @@ class AsyncGraphFilterEngine:
         return panel, k, b
 
     def _run_apply(self, batch) -> list[np.ndarray]:
-        panel, k, b = self._pack(batch)
-        prog = self.cache.get(
-            ("apply", self.backend, panel.shape[0], b),
-            # The packed panel is built fresh per batch and dead after the
-            # call, so its device buffer is donated (launch.donation
-            # discipline): the apply lane allocates no net panel memory at
-            # steady state. Pinned by test_engine donation tests.
-            lambda: self.filt.panel_program(
-                backend=self.backend, donate=True, **self.opts),
-        )
-        out = np.asarray(prog(jnp.asarray(panel)))  # (eta, N, b)
+        pid = batch[0].ticket.panel
+        with obs.span("engine.pack", panel=pid):
+            panel, k, b = self._pack(batch)
+        with obs.span("engine.dispatch", panel=pid):
+            prog = self.cache.get(
+                ("apply", self.backend, panel.shape[0], b),
+                # The packed panel is built fresh per batch and dead after
+                # the call, so its device buffer is donated
+                # (launch.donation discipline): the apply lane allocates no
+                # net panel memory at steady state. Pinned by test_engine
+                # donation tests.
+                lambda: self.filt.panel_program(
+                    backend=self.backend, donate=True, **self.opts),
+            )
+            y = prog(jnp.asarray(panel))
+        with obs.span("engine.fetch", panel=pid):
+            out = np.asarray(y)  # (eta, N, b)
         self.applies += 1
         self.served += k
-        return [out[:, :, i] for i in range(k)]
+        with obs.span("engine.unpack", panel=pid):
+            return [out[:, :, i] for i in range(k)]
 
     def _run_solve(self, batch) -> list[SolveResult]:
-        panel, k, b = self._pack(batch)
+        pid = batch[0].ticket.panel
+        with obs.span("engine.pack", panel=pid):
+            panel, k, b = self._pack(batch)
         solve_backend = getattr(self.solver, "backend", None) or self.backend
-        prog = self.cache.get(
-            ("solve", solve_backend, panel.shape[0], b),
-            lambda: self._build_solve_program(panel.shape[0]),
-        )
-        res = prog(jnp.asarray(panel))
-        x = np.asarray(res.x)  # (N, b)
-        aux = None if res.aux is None else np.asarray(res.aux)
+        with obs.span("engine.dispatch", panel=pid):
+            prog = self.cache.get(
+                ("solve", solve_backend, panel.shape[0], b),
+                lambda: self._build_solve_program(panel.shape[0]),
+            )
+            res = prog(jnp.asarray(panel))
+        with obs.span("engine.fetch", panel=pid):
+            x = np.asarray(res.x)  # (N, b)
+            aux = None if res.aux is None else np.asarray(res.aux)
         self.solves += 1
         self.solved += k
-        return [
-            dataclasses.replace(res, x=x[:, i], aux=None if aux is None else aux[..., i])
-            for i in range(k)
-        ]
+        with obs.span("engine.unpack", panel=pid):
+            return [
+                dataclasses.replace(res, x=x[:, i], aux=None if aux is None else aux[..., i])
+                for i in range(k)
+            ]
 
     def _build_solve_program(self, n: int):
         """Compile the whole solve when the spec allows, else pass through.
@@ -410,7 +442,11 @@ class AsyncGraphFilterEngine:
 
     @property
     def recompiles(self) -> int:
-        """Compiled-program builds so far (cache misses; 0 in steady state)."""
+        """Panel programs built so far: the cache's misses, one per
+        (lane, backend, N, bucket). On a traceable backend each is one
+        trace and one XLA compile of the jitted panel program, on its first
+        call (pinned against :mod:`repro.obs`'s compile counter); 0 in
+        steady state."""
         return self.cache.misses
 
     @property

@@ -28,9 +28,10 @@ class CompiledPanelCache:
 
     A "program" is whatever the builder returns — a ``jax.jit``-wrapped
     apply for traceable backends, a plain callable otherwise; the cache
-    only guarantees the builder runs once per key. Because every cached
-    program is fed exactly one input shape (its bucket), one miss
-    corresponds to one jit trace: ``misses`` IS the recompile count.
+    only guarantees ``build`` runs once per key. A miss builds the
+    wrapper only; the trace and compile follow on its first call. Because
+    every cached jitted program is fed exactly one input shape (its
+    bucket), that is one compile per miss.
     """
 
     def __init__(self) -> None:
@@ -54,11 +55,6 @@ class CompiledPanelCache:
 
     def __contains__(self, key: Hashable) -> bool:
         return key in self._programs
-
-    @property
-    def recompiles(self) -> int:
-        """Alias for ``misses`` — each miss is one program build/trace."""
-        return self.misses
 
     def stats(self) -> dict[str, int]:
         """Counters snapshot: ``programs`` / ``hits`` / ``misses``."""

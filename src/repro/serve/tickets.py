@@ -45,6 +45,13 @@ class Ticket:
         True once the scheduler filled ``result``/``t_done``.
     t_done : float, optional
         Clock reading at completion.
+    panel : int, optional
+        Id of the panel that answered it, shared with that panel's
+        ``engine.*`` spans; set only while :mod:`repro.obs` records.
+    queue_s : float, optional
+        Seconds it waited in the queue: its panel's start less
+        ``t_submit``, on the same clock; set only while :mod:`repro.obs`
+        records.
     """
 
     tid: int
@@ -55,6 +62,8 @@ class Ticket:
     result: Any = None
     done: bool = False
     t_done: float | None = None
+    panel: int | None = None
+    queue_s: float | None = None
 
     @property
     def latency_s(self) -> float | None:

@@ -342,7 +342,7 @@ def lasso_panel_program(
     # concrete operands instead of baking preparation into the trace.
     filt.prepare_backend(backend, **opts)
 
-    def run(y: jax.Array):
+    def panel_solve(y: jax.Array):
         problem = LassoProblem(filt=filt, y=y, mu=mu, step=step)
         y2, tau, fwd, adj, soft, l1 = _lasso_setup(problem, backend, opts)
         stepf, init, final = machine(y2, tau, fwd, adj, soft, l1)
@@ -355,7 +355,7 @@ def lasso_panel_program(
         a = final(state)
         return filt.adjoint(a, backend=backend, **opts), a, hist
 
-    return run
+    return panel_solve
 
 
 def solve(problem, *, method: str | None = None, **kw) -> SolveResult:
