@@ -248,11 +248,13 @@ class BsrBackend:
 
     ``prepare`` spatially reorders the vertices (recursive coordinate
     bisection) so nonzeros cluster into dense MXU tiles, then converts the
-    Laplacian to Block-ELL. ``apply`` picks the fused union-combine kernel
-    when the autotune table says the VMEM working set fits, else chains the
-    stepwise kernel.
+    Laplacian to Block-ELL at the block size ``autotune.choose_block_size``
+    derives from the Laplacian's tile occupancy. ``apply`` picks the fused
+    union-combine kernel when the autotune table says the VMEM working set
+    fits, else chains the stepwise kernel.
 
-    Options: ``block_size`` (prepare; default 8), ``interpret`` (default:
+    Options: ``block_size`` (prepare; default: derived; multi-shift
+    filters keep 8), ``interpret`` (default:
     True on the CPU backend only), ``f_tile`` / ``fuse`` overrides, and
     ``krylov_dtype`` (apply; default f32 — ``"bfloat16"`` halves the
     kernels' Krylov working set while all combines stay f32, widening
@@ -264,33 +266,50 @@ class BsrBackend:
     # traceable: pallas_call (or interpret mode) traces fine in scan.
     capabilities = BackendCapabilities(traceable=True, multi_shift=True)
 
-    def prepare(self, filt, *, block_size: int = 8, **_):
+    def prepare(self, filt, *, block_size: int | None = None, **_):
         g = _require_graph(filt, self.name)
         n = g.n_vertices
-        if g.coords is not None:
-            perm = graph_lib.spatial_partition_order(
-                np.asarray(g.coords), max(n // block_size, 1)
-            )
-        else:
+        base = autotune.BLOCK_CANDIDATES[0]
+        if g.coords is None:
             perm = np.arange(n)
+        else:
+            # Bisection into a power-of-two count of parts nests: each
+            # coarser candidate's slab is a run of whole finest slabs
+            # (whole bisection cells when N is base * 2^k), so one order
+            # serves every block size.
+            parts = 1 << (-(-n // base) - 1).bit_length()
+            perm = graph_lib.spatial_partition_order(np.asarray(g.coords), parts)
         inv = np.empty(n, dtype=np.int64)
         inv[perm] = np.arange(n)
         if filt.n_shifts > 1:
+            # The joint jnp einsum grows with block^2, which the chooser's
+            # model does not describe: the finest block unless asked.
             bells = tuple(
                 kref.bsr_from_dense(
-                    np.asarray(s.laplacian(), np.float64)[
-                        np.ix_(perm, perm)
-                    ],
-                    block_size,
+                    np.asarray(s.laplacian())[np.ix_(perm, perm)],
+                    block_size or base,
                 )
                 for s in filt.shifts
             )
             return _BsrMultiState(
                 bells=bells, perm=perm, inv=inv, n=n, n_pad=bells[0].n
             )
-        lap = np.asarray(g.laplacian(), np.float64)
-        with obs.span("bsr.block_ell", n=n, block_size=block_size):
-            bell = kref.bsr_from_dense(lap[np.ix_(perm, perm)], block_size)
+        lap = np.asarray(g.laplacian())
+        with obs.span("bsr.block_ell", n=n) as sp:
+            lap = lap[np.ix_(perm, perm)]
+            if block_size is None:
+                fine = kref.tile_mask(lap, base)
+                choice = autotune.choose_block_size(fine, filt.order)
+                block_size = choice.block_size
+                mask = kref.tile_mask(fine, block_size // base)
+                sp.set(chosen="model", order_ms=1e3 * choice.order_s,
+                       base_order_ms=1e3 * choice.base_order_s)
+            else:
+                mask = kref.tile_mask(lap, block_size)
+                sp.set(chosen="user")
+            bell = kref.bsr_from_dense(lap, block_size, mask=mask)
+            sp.set(block_size=block_size, k_max=bell.k_max,
+                   fill=float(mask.sum()) / (bell.n_block_rows * bell.k_max))
         return _BsrState(bell=bell, perm=perm, inv=inv, n=n, n_pad=bell.n)
 
     def _forward(self, state: _BsrState, f):

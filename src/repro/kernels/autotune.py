@@ -6,8 +6,11 @@ Instead we keep a small table of preferred tiles keyed by coarse shape
 buckets, and a deterministic VMEM-budget model decides whether the fused
 kernel fits (DESIGN.md Sec. 6.3).
 
-The decision this module makes:
+The decisions this module makes:
 
+* ``block_size`` — the Block-ELL tile edge (:func:`choose_block_size`),
+  from the Laplacian's own tile occupancy and a cost model, fitted on the
+  chip, of the stepwise chain and of what the tiles add to set-up,
 * ``f_tile``  — the F-dimension tile both kernels pipeline over,
 * ``fuse``    — whether the fused union-combine kernel
   (:func:`repro.kernels.cheb_bsr.cheb_union_pallas`) fits: it keeps the
@@ -22,8 +25,18 @@ import dataclasses
 import math
 
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["Tiling", "select_tiling", "union_vmem_bytes"]
+from repro.kernels.ref import tile_mask
+
+__all__ = [
+    "BLOCK_CANDIDATES",
+    "BlockChoice",
+    "Tiling",
+    "choose_block_size",
+    "select_tiling",
+    "union_vmem_bytes",
+]
 
 # Bytes the fused working set may take of the compiler's scoped VMEM
 # limit (16 MiB on v5e), leaving room for the compiler's own scratch.
@@ -31,6 +44,36 @@ __all__ = ["Tiling", "select_tiling", "union_vmem_bytes"]
 # tests exercise the TPU code paths. ``tests/test_tpu_compile.py`` compiles
 # a fused shape near this budget for v5e.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
+
+# Block sizes ``choose_block_size`` weighs, finest first. Each is a whole
+# number of finest slabs, so one occupancy mask at the finest serves all.
+BLOCK_CANDIDATES = (8, 16, 32, 64, 128, 256)
+
+# The stepwise chain's cost model (``_order_s``): one grid step of
+# ``cheb_step_pallas`` costs STEP_S, plus the larger of its DMA (the tile
+# and the gathered (block, f_tile) slab at HBM_BYTES_PER_S, the v5e's
+# published bandwidth) and its HIGHEST-precision contraction (at
+# MXU_F32_FLOPS_PER_S). STEP_S and MXU_F32_FLOPS_PER_S are fitted to a
+# TPU v5e sweep of block_size 8..256 at the 16,384-sensor field, F=128:
+# 0.223, 0.238, 0.260, 0.300, 0.366 and 0.711 us a step, each within 3%
+# of the model (PERF.md, Findings).
+STEP_S = 0.2165e-6
+HBM_BYTES_PER_S = 819e9
+MXU_F32_FLOPS_PER_S = 34e12
+
+# Set-up seconds a byte of float32 tiles adds where a jit closes over the
+# prepared filter, as the serving engine's ``jit(panel_apply)`` and a
+# jitted ``GraphFilter.apply`` do: the program then embeds the tiles as a
+# constant, and its persistent-cache load grows with their bytes. Slope
+# of a least-squares line through the warm loads of such a program on a
+# TPU v5e, 1.31, 2.25, 4.01 and 7.05 s at blocks 8, 64, 128 and 256 of
+# the 16,384-sensor field (6.3, 37.7, 83.9 and 151 MB; PERF.md, Findings).
+# A cold compile grows by about 7.4e-8 s a byte.
+SETUP_S_PER_TILE_BYTE = 4.0e-8
+# Applies of the filter that the tiles' set-up is weighed against. Both
+# this and the slope above rest on that one field; at it the pick is 128
+# from about 460 to 1,840 applies, 64 below and 256 above.
+AMORTIZE_APPLIES = 1_000
 
 # Preferred f_tile per (block_size bucket, dtype bucket), widest first:
 # MXU-aligned 128 everywhere F allows it. No entry has been timed on a
@@ -61,6 +104,21 @@ class Tiling:
     f_tile: int
     fuse: bool
     vmem_bytes: int
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockChoice:
+    """The Block-ELL block size :func:`choose_block_size` picked.
+
+    Attributes:
+      block_size: the tile edge.
+      order_s: predicted seconds of one order of the stepwise chain.
+      base_order_s: the same at the finest candidate.
+    """
+
+    block_size: int
+    order_s: float
+    base_order_s: float
 
 
 def _vmem_array_bytes(shape, dtype) -> int:
@@ -169,3 +227,47 @@ def select_tiling(
             krylov_dtype=krylov_dtype,
         ),
     )
+
+
+def _order_s(block: int, n_rows: int, k_max: int) -> float:
+    """Predicted seconds of one order of the stepwise chain
+    (``cheb_step_pallas``) on float32 operands: ``n_rows * k_max`` grid
+    steps, each ``STEP_S + max(dma, mxu)``. HBM tiles the last two dims
+    like VMEM, so a tile below 128 lanes moves as a padded one; the MXU
+    contracts over 128-deep passes. The F tile is 128 (``min(F, 128)``:
+    F is not known at prepare, and 128 is the width of a full panel)."""
+    f_tile = 128
+    dma = (_vmem_array_bytes((block, block), jnp.float32)
+           + _vmem_array_bytes((block, f_tile), jnp.float32)) / HBM_BYTES_PER_S
+    rows = -(-block // 8) * 8
+    depth = -(-block // 128) * 128
+    mxu = 2 * rows * depth * f_tile / MXU_F32_FLOPS_PER_S
+    return n_rows * k_max * (STEP_S + max(dma, mxu))
+
+
+def choose_block_size(mask: np.ndarray, orders: int) -> BlockChoice:
+    """The candidate block size of least predicted cost: the set-up its
+    tiles add (``SETUP_S_PER_TILE_BYTE``) plus ``AMORTIZE_APPLIES`` applies
+    of ``orders`` steps of the stepwise chain.
+
+    ``mask`` is the Laplacian's tile mask (``kernels.ref.tile_mask``) at
+    the finest candidate (``BLOCK_CANDIDATES[0]``) on the vertex order
+    every candidate is built on. A coarse tile holds a nonzero iff one of
+    its fine tiles does, so each coarser candidate's occupancy is pooled
+    from it, with no second pass over the Laplacian. Candidates larger
+    than the padded vertex count are not weighed. Ties go to the smaller
+    block.
+    """
+    base = BLOCK_CANDIDATES[0]
+    n_pad = mask.shape[0] * base
+    priced = []  # (cost_s, block, order_s)
+    for b in BLOCK_CANDIDATES:
+        if b > n_pad:
+            break
+        per_row = tile_mask(mask, b // base).sum(axis=1)
+        n_rows, k_max = len(per_row), max(int(per_row.max()), 1)
+        order_s = _order_s(b, n_rows, k_max)
+        setup_s = SETUP_S_PER_TILE_BYTE * 4 * n_rows * k_max * b * b
+        priced.append((setup_s + AMORTIZE_APPLIES * orders * order_s, b, order_s))
+    _, block, order_s = min(priced, key=lambda c: c[0])  # the first on ties
+    return BlockChoice(block, order_s, base_order_s=priced[0][2])

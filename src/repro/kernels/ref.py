@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BlockEll", "bsr_from_dense", "bsr_to_dense",
+__all__ = ["BlockEll", "tile_mask", "bsr_from_dense", "bsr_to_dense",
            "bsr_matvec_ref", "cheb_step_ref", "cheb_apply_bsr_ref"]
 
 
@@ -61,27 +61,47 @@ class BlockEll:
         return self.nnz_blocks / (self.n_block_rows**2)
 
 
-def bsr_from_dense(mat, block_size: int, dtype=jnp.float32) -> BlockEll:
+def tile_mask(mat, block_size: int) -> np.ndarray:
+    """(nb, nb) bool: which ``(block_size x block_size)`` tiles of the
+    (N, N) matrix, zero-padded to a multiple of ``block_size``, hold a
+    nonzero. One pass over the matrix."""
+    nz = np.asarray(mat) != 0
+    n = nz.shape[0]
+    nb = -(-n // block_size)
+    pad = nb * block_size - n
+    if pad:
+        nz = np.pad(nz, ((0, pad), (0, pad)))
+    # Rows first, over contiguous memory; then the short column groups.
+    rows = nz.reshape(nb, block_size, nb * block_size).any(axis=1)
+    return rows.reshape(nb, nb, block_size).any(axis=2)
+
+
+def bsr_from_dense(mat, block_size: int, dtype=jnp.float32, *,
+                   mask: np.ndarray | None = None) -> BlockEll:
     """Convert a dense (N, N) matrix to Block-ELL (host-side, build time).
 
     N is zero-padded up to a multiple of ``block_size``. ``k_max`` is the
-    max number of nonzero tiles in any block-row (>= 1).
+    max number of nonzero tiles in any block-row (>= 1). ``mask`` is
+    ``tile_mask(mat, block_size)`` where the caller has it already; the
+    matrix is then read only at its nonzero tiles, which are gathered in
+    float64 whatever the matrix's dtype.
     """
-    m = np.asarray(mat, dtype=np.float64)
-    n = m.shape[0]
-    n_pad = ((n + block_size - 1) // block_size) * block_size
-    full = np.zeros((n_pad, n_pad))
-    full[:n, :n] = m
-    nb = n_pad // block_size
-    tiles = full.reshape(nb, block_size, nb, block_size).transpose(0, 2, 1, 3)
-    nz = np.any(tiles != 0.0, axis=(-1, -2))  # (nb, nb)
-    k_max = max(int(nz.sum(axis=1).max()), 1)
+    m = np.asarray(mat)
+    if mask is None:
+        mask = tile_mask(m, block_size)
+    nb = mask.shape[0]
+    pad = nb * block_size - m.shape[0]
+    if pad:
+        m = np.pad(m, ((0, pad), (0, pad)))
+    tiles = m.reshape(nb, block_size, nb, block_size)
+    per_row = mask.sum(axis=1)
+    k_max = max(int(per_row.max()), 1)
+    rows, js = np.nonzero(mask)  # row-major: each row's tiles in column order
+    slots = np.arange(len(rows)) - np.repeat(np.cumsum(per_row) - per_row, per_row)
     blocks = np.zeros((nb, k_max, block_size, block_size))
     cols = np.zeros((nb, k_max), dtype=np.int32)
-    for i in range(nb):
-        js = np.nonzero(nz[i])[0]
-        blocks[i, : len(js)] = tiles[i, js]
-        cols[i, : len(js)] = js
+    blocks[rows, slots] = tiles[rows, :, js, :]
+    cols[rows, slots] = js
     return BlockEll(jnp.asarray(blocks, dtype), jnp.asarray(cols))
 
 

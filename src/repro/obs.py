@@ -9,9 +9,10 @@ One module, two instruments:
   nesting and args are read there), and adds its count, total and self
   seconds to a per-name aggregate that drops nothing. Self time is the
   span's duration less what its child spans (on the same thread) cover.
-  While recording is off, ``span`` returns one
-  shared no-op context after a single flag test, and records nothing;
-  callers that compute an id for a span's args test ``enabled()`` first.
+  Args known only inside the span go in by ``set`` on the context it
+  yields. While recording is off, ``span`` returns one shared no-op
+  context after a single flag test, and records nothing; callers that
+  compute an id for a span's args test ``enabled()`` first.
 * **Compile counter**, always on: listeners registered once through
   ``jax.monitoring`` count jaxpr traces, XLA compiles and persistent-cache
   loads, each with its ``fun_name``, its seconds, the innermost program
@@ -55,7 +56,6 @@ _KEEP_EVENTS = 4096
 _on = False
 _lock = threading.Lock()
 _local = threading.local()
-_NOOP = contextlib.nullcontext()
 
 _agg: dict[str, list] = {}  # name -> [count, total_ns, self_ns]
 # kind -> [n, s, by fun, n profiled, s profiled]
@@ -101,6 +101,22 @@ class _Span:
             rec[2] += dur - self.child_ns
         self.ann.__exit__(*exc)
         return False
+
+    def set(self, **args) -> None:
+        """Add args that are known only inside the span."""
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
+
+class _NoSpan(contextlib.nullcontext):
+    def __enter__(self):
+        return self
+
+    def set(self, **args) -> None:
+        pass
+
+
+_NOOP = _NoSpan()
 
 
 def span(name: str, **args):

@@ -4,7 +4,8 @@ No chip is attached: ``get_topology_desc`` describes one, and the TPU
 compiler raises here what it would raise on the chip (SMEM or VMEM
 exhaustion, block shapes the lowering refuses). Shapes are those of the
 16,384-sensor smoke field (``chip_smoke.py``): F=128 signals, an eta=5
-bank at order M=20, the Block-ELL tiling at block 8 and block 128.
+bank at order M=20, the Block-ELL tiling at every block size the ``bsr``
+backend can choose.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every pytest worker imports this
@@ -26,8 +27,9 @@ F = 128
 ETA = 5
 ORDER = 20
 LMAX = 30.0
-# k_max of the smoke field's Block-ELL tiling (seed 0) at each block size.
-K_MAX = {8: 13, 128: 10}
+# k_max of the smoke field's Block-ELL tiling (seed 0) at each block size
+# ``autotune.choose_block_size`` weighs.
+K_MAX = {8: 13, 16: 9, 32: 10, 64: 9, 128: 10, 256: 9}
 
 
 @pytest.fixture(scope="module")
@@ -72,10 +74,12 @@ def _coeffs(eta, order):
                  for j in range(eta))
 
 
-@pytest.mark.parametrize("block", [8, 128])
+@pytest.mark.parametrize("block", sorted(K_MAX))
 def test_step_kernel_compiles(one_chip, block):
-    """The stepwise kernel at N=16384; block 8 once ran out of SMEM (its
-    2-D prefetched column table padded to 128 words per block-row)."""
+    """The stepwise kernel at N=16384 at every block the chooser can
+    return; block 8 once ran out of SMEM (its 2-D prefetched column table
+    padded to 128 words per block-row)."""
+    assert set(K_MAX) == set(autotune.BLOCK_CANDIDATES)
     blocks, cols = _bell_specs(one_chip, N, block, K_MAX[block])
     t = _spec(one_chip, (N, F))
     _compile(
@@ -83,11 +87,13 @@ def test_step_kernel_compiles(one_chip, block):
         blocks, cols, t, t)
 
 
-def test_bf16_tiling_answer_compiles(one_chip):
+@pytest.mark.parametrize("block", autotune.BLOCK_CANDIDATES)
+def test_bf16_tiling_answer_compiles(one_chip, block):
     """What ``select_tiling`` answers for the smoke apply in bf16 Krylov
-    mode compiles. It once fused at f_tile=8, a block the lowering
-    refuses; the answer now is the stepwise chain at a 128-lane tile."""
-    block, k_max = 8, K_MAX[8]
+    mode compiles at every block the chooser can return. It once fused at
+    f_tile=8, a block the lowering refuses; the answer now is the stepwise
+    chain at a 128-lane tile."""
+    k_max = K_MAX[block]
     tiling = autotune.select_tiling(
         N, F, ETA, N // block, k_max, block, krylov_dtype=jnp.bfloat16)
     assert tiling.f_tile % 128 == 0 or tiling.f_tile == F
