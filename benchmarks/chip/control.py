@@ -48,8 +48,7 @@ def main(argv=None) -> int:
     use_compile_cache()
     jax.config.update("jax_default_matmul_precision", config["matmul_precision"])
     n, f = config["n_vertices"], config["signal_batch"]
-    coords, adj, lmax, _ = field.make_field(config["field_seed"], n, config["kappa"],
-                                            config["sigma"])
+    coords, adj, lmax, _ = harness.make_graph(config)
     coeffs = field.cheb_coefficients(field.sgwt_bank(lmax, config["n_scales"]),
                                      config["order"], lmax)
     filt = harness.build_program(config, {"coords": coords, "adj": adj, "lmax": lmax})

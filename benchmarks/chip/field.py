@@ -8,7 +8,8 @@ Everything here is the yardstick's own copy, independent of ``repro``:
   kappa keeps the paper's connectivity margin at any N: at N=500 its
   kappa=0.075 gives pi kappa^2 N = 1.42 ln N, and scaling kappa by
   sqrt(500 ln N / (N ln 500)) keeps that ratio; sigma keeps sigma/kappa;
-* the Anderson--Morley spectrum bound (Sec. IV-A);
+* the Anderson--Morley spectrum bound (Sec. IV-A), which any graph
+  generator under ``graphs/`` may call on its adjacency;
 * the SGWT bank of Hammond, Vandergheynst & Gribonval (the paper's
   Sec. V-C operator) and the Chebyshev coefficients of eq. (8) by
   Chebyshev--Gauss quadrature.
@@ -37,13 +38,21 @@ def field_radii(n: int) -> tuple[float, float]:
     return PAPER_KAPPA * scale, PAPER_SIGMA * scale
 
 
+def anderson_morley(adj):
+    """The Anderson--Morley bound max_{i~j} d_i + d_j on the Laplacian
+    spectrum of the (N, N) adjacency ``adj`` (paper Sec. IV-A), as a jnp
+    scalar; a graph generator states it to the program as ``lmax``."""
+    deg = adj.sum(axis=1)
+    return jnp.max(jnp.where(adj > 0, deg[:, None] + deg[None, :], 0.0))
+
+
 def make_field(field_seed: int, n: int, kappa: float, sigma: float):
     """(coords (n, 2), adjacency (n, n), lmax, nnz) of the field.
 
     Coordinates and adjacency stay on the device. ``lmax`` is the
-    Anderson--Morley bound max_{i~j} d_i + d_j as a host float: the
-    deployment's stated spectrum bound, handed to the program and used by
-    the reference alike. ``nnz`` counts the adjacency's nonzero entries.
+    Anderson--Morley bound as a host float: the deployment's stated
+    spectrum bound, handed to the program and used by the reference
+    alike. ``nnz`` counts the adjacency's nonzero entries.
     """
 
     @jax.jit
@@ -52,10 +61,7 @@ def make_field(field_seed: int, n: int, kappa: float, sigma: float):
         d2 = jnp.sum((coords[:, None, :] - coords[None, :, :]) ** 2, axis=-1)
         w = jnp.where(d2 <= kappa**2, jnp.exp(-d2 / (2.0 * sigma**2)), 0.0)
         adj = jnp.where(jnp.eye(n, dtype=bool), 0.0, w)
-        deg = adj.sum(axis=1)
-        lmax = jnp.max(jnp.where(adj > 0, deg[:, None] + deg[None, :], 0.0))
-        nnz = jnp.count_nonzero(adj)
-        return coords, adj, lmax, nnz
+        return coords, adj, anderson_morley(adj), jnp.count_nonzero(adj)
 
     coords, adj, lmax, nnz = build(jax.random.PRNGKey(field_seed))
     return coords, adj, float(lmax), int(nnz)

@@ -1,15 +1,18 @@
 """One run of one benchmark cell, driven by the files that ``BENCHMARK.json``
 names. ``run.py`` is the command line; tests call ``run_cell`` directly.
 
-A cell is a configuration (``configs/<config>.json``: the deployment, its
-field and bank, the precision it states and the limit of its check) under
-a traffic mix (``traffic/<traffic>.json``: which loop drives which
-backend, with its parameters). Per-layer metrics are read by
-``metrics/<name>.py``, each a ``read(ctx)`` that returns a number or None.
-Adding a cell, a mix or a metric adds files; none of this code changes.
+A cell is a configuration (``configs/<config>.json``: the deployment, the
+graph it names, its bank, the precision it states and the limit of its
+check) under a traffic mix (``traffic/<traffic>.json``: which loop drives
+which backend, with its parameters). The graph is made by
+``graphs/<graph>.py``, a ``make(config)`` that returns (coords or None,
+adjacency (N, N) float32 on the device, lmax, nnz). Per-layer metrics are
+read by ``metrics/<name>.py``, each a ``read(ctx)`` that returns a number
+or None. Adding a cell, a mix, a graph or a metric adds files; none of
+this code changes.
 
 Set-up (counted in ``setup_s``, from process start): the device check,
-the field, the program's filter and backend state, and one warm-up of
+the graph, the program's filter and backend state, and one warm-up of
 every shape the window uses. The window then runs ``--seconds`` of the
 cell's loop, traced with ``--trace 1``. After it closes: the device's
 peak memory, the per-layer metrics, then the program's state is dropped
@@ -43,6 +46,8 @@ import peaks  # noqa: E402
 import reference  # noqa: E402
 import trace_reduce  # noqa: E402
 
+GRAPHS = HERE / "graphs"
+
 # Seed purposes: each draws its own stream from the run's --seed.
 COLUMNS, SAMPLE, TRACE, POOL = 1, 2, 3, 4
 WARM_INDEX = 2**30  # column batches of the warm-up, apart from the window's
@@ -67,6 +72,24 @@ def load_cell(name: str) -> tuple[dict, dict, dict]:
     traffic = json.loads((HERE / "traffic" / f"{cell['traffic']}.json").read_text())
     cell = dict(cell, _bench=bench)
     return cell, config, traffic
+
+
+def load_module(path: Path):
+    """The Python file ``path`` as a module of its own."""
+    name = "bench_" + "_".join(path.with_suffix("").parts[-2:]).replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def make_graph(config: dict):
+    """(coords or None, adjacency, lmax, nnz) of the graph the configuration
+    names, made by ``graphs/<config["graph"]>.py``."""
+    if "graph" not in config:
+        raise ValueError(f"configuration {config.get('name')!r} names no graph: "
+                         f'it needs "graph": "<name>" of a file graphs/<name>.py')
+    return load_module(GRAPHS / f"{config['graph']}.py").make(config)
 
 
 def require_tpu(n_chips: int) -> dict:
@@ -100,7 +123,8 @@ def memory_peak_bytes(n: int) -> int:
 
 
 def build_program(config: dict, fld: dict):
-    """The program's filter over the benchmark's field, as a user builds it."""
+    """The program's filter over the benchmark's graph, as a user builds it;
+    ``fld["coords"]`` is None for a graph without coordinates."""
     from repro.core import multipliers
     from repro.core.graph import SensorGraph
     from repro.filters import GraphFilter
@@ -338,11 +362,7 @@ LOOPS = {"closed": ClosedLoop, "open": OpenLoop}
 
 
 def read_metric(name: str, ctx: dict):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return load_module(HERE / "metrics" / f"{name}.py").read(ctx)
 
 
 def cell_metrics(bench: dict, cell: str, kind: str) -> list[dict]:
@@ -355,7 +375,7 @@ def cell_metrics(bench: dict, cell: str, kind: str) -> list[dict]:
 
 def setup_cell(workload: str, seed: int, *, require_chip: bool = True,
                overrides: dict | None = None) -> dict:
-    """Everything before the window: the device check, the field, the
+    """Everything before the window: the device check, the graph, the
     program's filter and backend state, and the warm-up."""
     cell, config, traffic = load_cell(workload)
     overrides = overrides or {}
@@ -373,8 +393,7 @@ def setup_cell(workload: str, seed: int, *, require_chip: bool = True,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_default_matmul_precision", config["matmul_precision"])
 
-    coords, adj, lmax, nnz_adj = field.make_field(
-        config["field_seed"], config["n_vertices"], config["kappa"], config["sigma"])
+    coords, adj, lmax, nnz_adj = make_graph(config)
     filt = build_program(config, {"coords": coords, "adj": adj, "lmax": lmax})
     loop = LOOPS[traffic["loop"]](filt, config, traffic, seed)
     loop.warm()

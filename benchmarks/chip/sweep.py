@@ -1,7 +1,7 @@
 """Find the knee of an open-loop cell: one set-up, then the window at each
 offered rate in turn, on the chip.
 
-    python3 benchmarks/chip/sweep.py --workload field2k.serve --seed 5 --seconds 8 \\
+    python3 benchmarks/chip/sweep.py --workload <open-loop cell> --seed 5 --seconds 8 \\
         --rates 2000,3000,4000,5000,6000
 
 Prints one JSON line per rate: p50/p95 latency, p95 of the first and the

@@ -66,7 +66,10 @@ def test_benchmark_json_names_files_and_cells():
         assert (ROOT / c["file"]).is_file() and c["file"].startswith("benchmarks/chip/")
         conf = json.loads((ROOT / c["file"]).read_text())
         assert set(c["reduced"]) == set(conf["reduced"])
-        assert np.allclose(field.field_radii(conf["n_vertices"]), (conf["kappa"], conf["sigma"]))
+        assert (HERE / "graphs" / f"{conf['graph']}.py").is_file()
+        if conf["graph"] == "sensor_field":
+            assert np.allclose(field.field_radii(conf["n_vertices"]),
+                               (conf["kappa"], conf["sigma"]))
     for m in per_layer.values():
         assert (HERE / "metrics" / f"{m['name']}.py").is_file()
         assert m["moves"] in e2e
@@ -77,6 +80,91 @@ def test_benchmark_json_names_files_and_cells():
         assert "setup_s" in {m["name"] for m in mine} and len(mine) >= 2
         layer = [m for m in per_layer.values() if w["name"] in m.get("workloads", [])]
         assert layer and all(m["moves"] in {x["name"] for x in mine} for m in layer)
+
+
+# -- graphs -----------------------------------------------------------------
+
+
+def test_sensor_field_graph_is_the_field_bit_for_bit():
+    """``graphs/sensor_field.py`` builds, at N=2,048, the adjacency, lmax and
+    nnz of the field recipe as it was written before graphs were files."""
+    import jax
+    import jax.numpy as jnp
+
+    import harness
+
+    conf = json.loads((HERE / "configs" / "sensor_field_2k.json").read_text())
+    n, kappa, sigma = conf["n_vertices"], conf["kappa"], conf["sigma"]
+
+    @jax.jit
+    def recipe(key):
+        coords = jax.random.uniform(key, (n, 2), jnp.float32)
+        d2 = jnp.sum((coords[:, None, :] - coords[None, :, :]) ** 2, axis=-1)
+        w = jnp.where(d2 <= kappa**2, jnp.exp(-d2 / (2.0 * sigma**2)), 0.0)
+        adj = jnp.where(jnp.eye(n, dtype=bool), 0.0, w)
+        deg = adj.sum(axis=1)
+        lmax = jnp.max(jnp.where(adj > 0, deg[:, None] + deg[None, :], 0.0))
+        return coords, adj, lmax, jnp.count_nonzero(adj)
+
+    want = recipe(jax.random.PRNGKey(conf["field_seed"]))
+    coords, adj, lmax, nnz = harness.make_graph(conf)
+    assert np.array_equal(np.asarray(coords), np.asarray(want[0]))
+    assert np.array_equal(np.asarray(adj), np.asarray(want[1]))
+    assert lmax == float(want[2]) and nnz == int(want[3]) == 2 * conf["field_edges"]
+
+
+RING_GRAPH = """
+import jax, jax.numpy as jnp
+import field
+
+def make(config):
+    n = config["n_vertices"]
+    i = jnp.arange(n)
+    hop = jnp.abs(i[:, None] - i[None, :])
+    hop = jnp.minimum(hop, n - hop)
+    w = jax.random.uniform(jax.random.PRNGKey(config["field_seed"]), (n, n), jnp.float32)
+    adj = jnp.where((hop > 0) & (hop <= 3), 0.5 + 0.5 * (w + w.T) / 2, 0.0)
+    return None, adj, float(field.anderson_morley(adj)), int(jnp.count_nonzero(adj))
+"""
+
+
+def test_a_new_graph_is_added_as_a_file(tmp_path, monkeypatch):
+    """A generator without coordinates, written as one file to a
+    ``graphs/`` directory, drives a correct closed-loop run; no file of the
+    harness changes."""
+    import harness
+
+    (tmp_path / "graphs").mkdir()
+    (tmp_path / "graphs" / "ring.py").write_text(RING_GRAPH)
+    monkeypatch.setattr(harness, "GRAPHS", tmp_path / "graphs")
+    conf = dict(json.loads((HERE / "configs" / "sensor_field_16k.json").read_text()),
+                name="ring_256", graph="ring")
+    mix = json.loads((HERE / "traffic" / "batch.json").read_text())
+    cell = {"name": "ring.batch", "config": "ring_256", "traffic": "batch", "chips": 1,
+            "_bench": BENCH}
+    monkeypatch.setattr(harness, "load_cell", lambda _name: (cell, conf, mix))
+    built = []
+    build_program = harness.build_program
+    monkeypatch.setattr(harness, "build_program",
+                        lambda config, fld: built.append(fld) or build_program(config, fld))
+    res = harness.run_cell("ring.batch", 2**31 + 3, 1.0, False, t_start=time.perf_counter(),
+                           require_chip=False,
+                           overrides={"config": {"n_vertices": SMALL_N, "signal_batch": 8},
+                                      "traffic": {"sample_applies": 2}})
+    assert res["correct"] and res["compared"]["answers_missing"]["value"] == 0
+    assert res["compared"]["rel_max_err"]["value"] < 1e-5
+    (fld,) = built
+    assert fld["coords"] is None and fld["adj"].shape == (SMALL_N, SMALL_N)
+    assert int(np.count_nonzero(np.asarray(fld["adj"]))) == 6 * SMALL_N
+
+
+def test_configuration_without_graph_is_refused():
+    import harness
+
+    conf = json.loads((HERE / "configs" / "sensor_field_2k.json").read_text())
+    del conf["graph"]
+    with pytest.raises(ValueError, match="'sensor_field_2k' names no graph"):
+        harness.make_graph(conf)
 
 
 # -- operations and bytes ---------------------------------------------------
