@@ -251,27 +251,27 @@ def tab_kernel(full: bool) -> None:
     lap = np.asarray(g.laplacian())
     order_perm = graph.spatial_partition_order(np.asarray(g.coords), 60)
     lap = lap[np.ix_(order_perm, order_perm)]
-    bell = kref.bsr_from_dense(lap, 8)
+    mat = kref.bsr_from_dense(lap, 8)
     lmax = float(g.lmax_bound())
     coeffs = chebyshev.cheb_coefficients(
         [multipliers.tikhonov(1.0, 1)], 20, lmax)
-    f = jax.random.normal(jax.random.PRNGKey(8), (bell.n, 8))
+    f = jax.random.normal(jax.random.PRNGKey(8), (mat.n, 8))
 
     def pallas_path():
-        return kops.cheb_apply_bsr(bell.blocks, bell.cols, f, coeffs, lmax,
+        return kops.cheb_apply_bsr(mat.blocks, mat.rows, mat.cols, f, coeffs, lmax,
                                    interpret=True)
 
     def ref_path():
-        return kref.cheb_apply_bsr_ref(bell, f, coeffs, lmax)
+        return kref.cheb_apply_bsr_ref(mat, f, coeffs, lmax)
 
     us_ref = _timeit(jax.jit(ref_path))
     got = pallas_path()
     want = ref_path()
     err = float(jnp.max(jnp.abs(got - want)))
-    dens = bell.nnz_blocks / bell.n_block_rows**2
+    dens = mat.n_tiles / mat.n_block_rows**2
     row("tab_kernel_cheb_bsr", us_ref,
         f"max_err={err:.1e};block_density={dens:.3f}"
-        f";nnz_blocks={bell.nnz_blocks};interpret_validated=1")
+        f";n_tiles={mat.n_tiles};interpret_validated=1")
 
 
 # --------------------------------------------------- filter backends ---
@@ -332,28 +332,28 @@ def tab_filter_backends(full: bool) -> None:
 
     # Structural comparison of the two Pallas paths on identical operands.
     state = get_backend("bsr").prepare(filt)
-    bell = state.bell
+    mat = state.mat
     fp = jnp.zeros((state.n_pad, 8), f.dtype).at[: state.n].set(f[state.perm])
     coeffs = filt.coeffs
     lmax = filt.lmax
+    operands = (mat.blocks, mat.rows, mat.cols, fp)
 
-    def fused(blocks, cols, x):
+    def fused(blocks, rows, cols, x):
         return kops.cheb_apply_bsr_fused(
-            blocks, cols, x, coeffs, lmax, interpret=True)
+            blocks, rows, cols, x, coeffs, lmax, interpret=True)
 
-    def stepwise(blocks, cols, x):
+    def stepwise(blocks, rows, cols, x):
         return kops.cheb_apply_bsr(
-            blocks, cols, x, jnp.asarray(coeffs, x.dtype), lmax,
+            blocks, rows, cols, x, jnp.asarray(coeffs, x.dtype), lmax,
             interpret=True)
 
-    step_out = stepwise(bell.blocks, bell.cols, fp)
+    step_out = stepwise(*operands)
     n_calls = {}
     for name, fn in (("fused", fused), ("stepwise", stepwise)):
-        jaxpr = jax.make_jaxpr(fn)(bell.blocks, bell.cols, fp)
+        jaxpr = jax.make_jaxpr(fn)(*operands)
         n_calls[name] = str(jaxpr).count("pallas_call")
-        err = float(jnp.max(jnp.abs(
-            fn(bell.blocks, bell.cols, fp) - step_out)))
-        us = _timeit(lambda: fn(bell.blocks, bell.cols, fp))
+        err = float(jnp.max(jnp.abs(fn(*operands) - step_out)))
+        us = _timeit(lambda: fn(*operands))
         row(f"tab_filter_union_{name}", us,
             f"pallas_calls={n_calls[name]};order={filt.order}"
             f";eta={filt.eta};max_err_vs_stepwise={err:.1e}")

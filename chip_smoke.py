@@ -1,5 +1,5 @@
 """Bring-up smoke of the main path on a TPU: GraphFilter -> ``bsr`` backend
-(Pallas Block-ELL kernels) -> AsyncGraphFilterEngine, on a 16,384-sensor
+(Pallas Block-CSR kernels) -> AsyncGraphFilterEngine, on a 16,384-sensor
 field at the widths of ``configs/sensor_gsp.py`` FULL (F=128 signals, order
 M=20, an eta=5 SGWT bank).
 
@@ -144,13 +144,11 @@ def timed_runs(fn, arg, n: int = 3):
 def bsr_phase(filt, f, ref) -> None:
     t0 = time.perf_counter()
     state = filt.prepare_backend("bsr")
-    bell = state.bell
+    mat = state.mat
     tiling = autotune.select_tiling(
-        state.n_pad, f.shape[1], filt.eta, bell.n_block_rows, bell.k_max,
-        bell.block_size, f.dtype)
-    log(phase="bsr_prepare", block_size=bell.block_size,
-        n_block_rows=bell.n_block_rows, k_max=bell.k_max,
-        nnz_blocks=bell.nnz_blocks, tiling=tiling,
+        state.n_pad, f.shape[1], filt.eta, mat.n_tiles, mat.block_size, f.dtype)
+    log(phase="bsr_prepare", block_size=mat.block_size,
+        n_block_rows=mat.n_block_rows, n_tiles=mat.n_tiles, tiling=tiling,
         seconds=time.perf_counter() - t0)
 
     for kd, bound in (("float32", F32_BOUND), ("bfloat16", BF16_BOUND)):

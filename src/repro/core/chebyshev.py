@@ -340,7 +340,7 @@ def cheb_apply_joint(
 
     Args:
       matvecs: R linear maps, ``matvecs[r](v) = S_r @ v`` for v shaped
-        like ``f`` (dense matmuls, Block-ELL kernels, or halo-exchange
+        like ``f`` (dense matmuls, Block-CSR kernels, or halo-exchange
         matvecs — each shift may run on its own exchange plan).
       f: input signal(s), shape (N,) or (N, F).
       coeffs: (eta, M_1+1, ..., M_R+1) joint coefficient tensor.

@@ -38,6 +38,7 @@ __all__ = [
     "is_connected",
     "khop_neighborhood",
     "spatial_partition_order",
+    "degree_order",
 ]
 
 
@@ -334,3 +335,17 @@ def spatial_partition_order(coords, n_parts: int) -> np.ndarray:
 
     with obs.span("graph.spatial_order", n=n):
         return rec(np.arange(n), n_parts)
+
+
+def degree_order(adjacency) -> np.ndarray:
+    """Order vertices without coordinates: by nonzeros a row, most first;
+    ties keep label order, so isolated vertices come last.
+
+    On a graph with skewed degrees (a Kronecker / R-MAT graph) this packs
+    the hubs' rows and columns into the first block-rows and -columns,
+    where their nonzeros share tiles. ``adjacency`` may be the Laplacian:
+    its diagonal adds one to every vertex with an edge, which keeps the
+    order. Returns a permutation of vertex ids.
+    """
+    deg = np.count_nonzero(np.asarray(adjacency), axis=1)
+    return np.argsort(-deg, kind="stable")

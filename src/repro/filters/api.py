@@ -119,7 +119,7 @@ class GraphFilter:
 
     Carries the *spectral* description only — the coefficient tensor, the
     spectrum bound(s), and the shift structure. Graph-operator operands
-    (dense Laplacians, Block-ELL tiles, partition plans) are built lazily
+    (dense Laplacians, Block-CSR tiles, partition plans) are built lazily
     per backend and cached.
 
     Parameters
@@ -383,7 +383,7 @@ class GraphFilter:
 
     def prepare_backend(self, backend: str = "dense", **opts) -> Any:
         """Eagerly build (and cache) ``backend``'s prepared state, and
-        return it (e.g. the Block-ELL operands of ``bsr``, the partition
+        return it (e.g. the Block-CSR operands of ``bsr``, the partition
         plan of ``halo``).
 
         Normally preparation happens lazily on the first apply; callers

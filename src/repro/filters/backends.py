@@ -4,7 +4,7 @@ Five graph-bound substrates plus one graph-free escape hatch:
 
 * ``dense``      — jnp reference: dense Laplacian matvec, ``lax.scan``
                    recurrence. The parity oracle for everything else.
-* ``bsr``        — Pallas Block-ELL: the fused union-combine kernel when
+* ``bsr``        — Pallas Block-CSR: the fused union-combine kernel when
                    the VMEM budget allows (one ``pallas_call`` per apply),
                    the stepwise per-order chain otherwise.
 * ``halo``       — ``shard_map`` vertex partition, per-order boundary
@@ -218,7 +218,7 @@ class DenseBackend:
 
 @dataclasses.dataclass(frozen=True)
 class _BsrState:
-    bell: kref.BlockEll
+    mat: kref.BlockCsr
     perm: np.ndarray  # vertex permutation applied before tiling
     inv: np.ndarray  # positions of the true vertices in permuted order
     n: int  # true vertex count
@@ -227,15 +227,15 @@ class _BsrState:
 
 @dataclasses.dataclass(frozen=True)
 class _BsrMultiState:
-    """Multi-shift Block-ELL state: one tiling per shift, shared layout.
+    """Multi-shift Block-CSR state: one tiling per shift, shared layout.
 
-    Every shift's Laplacian is permuted by the SAME spatial order (and
+    Every shift's Laplacian is permuted by the SAME vertex order (and
     padded to the same ``n_pad``) so the joint recurrence interleaves
     per-shift matvecs on one signal layout — the single-chip analog of
     the shared-layout partition plans.
     """
 
-    bells: tuple
+    mats: tuple
     perm: np.ndarray
     inv: np.ndarray
     n: int
@@ -244,14 +244,16 @@ class _BsrMultiState:
 
 @register_backend
 class BsrBackend:
-    """Pallas Block-ELL backend (DESIGN.md Sec. 3 + 6.3).
+    """Pallas Block-CSR backend (DESIGN.md Sec. 3 + 6.3).
 
-    ``prepare`` spatially reorders the vertices (recursive coordinate
-    bisection) so nonzeros cluster into dense MXU tiles, then converts the
-    Laplacian to Block-ELL at the block size ``autotune.choose_block_size``
-    derives from the Laplacian's tile occupancy. ``apply`` picks the fused
-    union-combine kernel when the autotune table says the VMEM working set
-    fits, else chains the stepwise kernel.
+    ``prepare`` orders the vertices so nonzeros cluster into dense MXU
+    tiles — recursive coordinate bisection where the graph has
+    coordinates, most nonzeros first (``graph.degree_order``) where it has
+    none — then converts the Laplacian to Block-CSR at the block size
+    ``autotune.choose_block_size`` derives from the Laplacian's tile
+    occupancy. ``apply`` picks the fused union-combine kernel when the
+    autotune table says the VMEM working set fits, else chains the
+    stepwise kernel, one grid step per stored tile.
 
     Options: ``block_size`` (prepare; default: derived; multi-shift
     filters keep 8), ``interpret`` (default:
@@ -270,47 +272,48 @@ class BsrBackend:
         g = _require_graph(filt, self.name)
         n = g.n_vertices
         base = autotune.BLOCK_CANDIDATES[0]
-        if g.coords is None:
-            perm = np.arange(n)
-        else:
-            # Bisection into a power-of-two count of parts nests: each
-            # coarser candidate's slab is a run of whole finest slabs
-            # (whole bisection cells when N is base * 2^k), so one order
-            # serves every block size.
-            parts = 1 << (-(-n // base) - 1).bit_length()
-            perm = graph_lib.spatial_partition_order(np.asarray(g.coords), parts)
+        multi = filt.n_shifts > 1
+        lap = None if multi else np.asarray(g.laplacian())
+        method = "degree" if g.coords is None else "spatial"
+        with obs.span("bsr.order", method=method, n=n):
+            if g.coords is None:
+                # The Laplacian's rows order as the adjacency's do.
+                perm = graph_lib.degree_order(g.adjacency if multi else lap)
+            else:
+                # Bisection into a power-of-two count of parts nests: each
+                # coarser candidate's slab is a run of whole finest slabs
+                # (whole bisection cells when N is base * 2^k), so one
+                # order serves every block size.
+                parts = 1 << (-(-n // base) - 1).bit_length()
+                perm = graph_lib.spatial_partition_order(np.asarray(g.coords), parts)
         inv = np.empty(n, dtype=np.int64)
         inv[perm] = np.arange(n)
-        if filt.n_shifts > 1:
+        if multi:
             # The joint jnp einsum grows with block^2, which the chooser's
             # model does not describe: the finest block unless asked.
-            bells = tuple(
-                kref.bsr_from_dense(
-                    np.asarray(s.laplacian())[np.ix_(perm, perm)],
-                    block_size or base,
-                )
-                for s in filt.shifts
-            )
-            return _BsrMultiState(
-                bells=bells, perm=perm, inv=inv, n=n, n_pad=bells[0].n
-            )
-        lap = np.asarray(g.laplacian())
+            b = block_size or base
+            mats = tuple(kref.bsr_from_dense(s.laplacian(), b, perm=perm) for s in filt.shifts)
+            return _BsrMultiState(mats=mats, perm=perm, inv=inv, n=n, n_pad=-(-n // b) * b)
         with obs.span("bsr.block_ell", n=n) as sp:
-            lap = lap[np.ix_(perm, perm)]
             if block_size is None:
-                fine = kref.tile_mask(lap, base)
+                fine = kref.permuted_tile_mask(lap, perm, base)
                 choice = autotune.choose_block_size(fine, filt.order)
                 block_size = choice.block_size
                 mask = kref.tile_mask(fine, block_size // base)
                 sp.set(chosen="model", order_ms=1e3 * choice.order_s,
                        base_order_ms=1e3 * choice.base_order_s)
             else:
-                mask = kref.tile_mask(lap, block_size)
+                mask = kref.permuted_tile_mask(lap, perm, block_size)
                 sp.set(chosen="user")
-            bell = kref.bsr_from_dense(lap, block_size, mask=mask)
-            sp.set(block_size=block_size, k_max=bell.k_max,
-                   fill=float(mask.sum()) / (bell.n_block_rows * bell.k_max))
-        return _BsrState(bell=bell, perm=perm, inv=inv, n=n, n_pad=bell.n)
+            mat = kref.bsr_from_dense(lap, block_size, perm=perm, mask=mask)
+            tiles = int(mask.sum())
+            sp.set(block_size=block_size, tiles=tiles, slots=mat.n_tiles,
+                   fill=tiles / mat.n_tiles)
+        n_rows = mask.shape[0]
+        obs.record_layout(backend=self.name, n=n, nnz=int(np.count_nonzero(lap)),
+                          block=block_size, block_rows=n_rows, stored_tiles=mat.n_tiles,
+                          tile_bytes=mat.tile_bytes)
+        return _BsrState(mat=mat, perm=perm, inv=inv, n=n, n_pad=n_rows * block_size)
 
     def _forward(self, state: _BsrState, f):
         """Permute + pad an (N, ...) signal into kernel layout."""
@@ -344,35 +347,34 @@ class BsrBackend:
         with jax.named_scope("repro.bsr.forward_perm"):
             fp, squeeze = self._forward(state, f)
         if isinstance(state, _BsrMultiState):
-            # Joint recurrence over the per-shift Block-ELL matvecs (the
+            # Joint recurrence over the per-shift Block-CSR matvecs (the
             # jnp reference oracle — the fused/stepwise Pallas kernels are
             # single-shift; the joint path's inner level reuses them via
             # cheb_apply's scan only in spirit, not in kernel).
             out = chebyshev.cheb_apply_joint(
-                [self._bell_matvec(b, state.n_pad) for b in state.bells],
+                [self._bsr_matvec(m, state.n_pad) for m in state.mats],
                 fp,
                 jnp.asarray(c, fp.dtype),
                 filt.shift_lmaxes,
             )
             out = out[:, state.inv]
             return out[:, :, 0] if squeeze else out
-        bell = state.bell
+        mat = state.mat
         tiling = autotune.select_tiling(
-            state.n_pad, fp.shape[1], c.shape[0],
-            bell.n_block_rows, bell.k_max, bell.block_size, fp.dtype,
-            krylov_dtype=kd,
+            state.n_pad, fp.shape[1], c.shape[0], mat.n_tiles, mat.block_size,
+            fp.dtype, krylov_dtype=kd,
         )
         if fuse is None:
             fuse = tiling.fuse
         ft = f_tile or tiling.f_tile
         if fuse:
             out = kops.cheb_apply_bsr_fused(
-                bell.blocks, bell.cols, fp, c, filt.lmax,
+                mat.blocks, mat.rows, mat.cols, fp, c, filt.lmax,
                 interpret=interpret, f_tile=ft, krylov_dtype=kd,
             )
         else:
             out = kops.cheb_apply_bsr(
-                bell.blocks, bell.cols, fp, jnp.asarray(c, fp.dtype),
+                mat.blocks, mat.rows, mat.cols, fp, jnp.asarray(c, fp.dtype),
                 filt.lmax, interpret=interpret, f_tile=ft, krylov_dtype=kd,
             )
         with jax.named_scope("repro.bsr.inverse_perm"):
@@ -380,18 +382,18 @@ class BsrBackend:
         return out[:, :, 0] if squeeze else out
 
     @staticmethod
-    def _bell_matvec(bell, n_pad: int):
-        """jnp Block-ELL matvec closure handling arbitrary trailing dims."""
+    def _bsr_matvec(mat, n_pad: int):
+        """jnp Block-CSR matvec closure handling arbitrary trailing dims."""
 
         def mv(v):
             flat = v.reshape(n_pad, -1)
-            return kref.bsr_matvec_ref(bell, flat).reshape(v.shape)
+            return kref.bsr_matvec_ref(mat, flat).reshape(v.shape)
 
         return mv
 
     def adjoint(self, filt, state, a, **_):
         # Adjoint = same recurrence on eta-stacked blocks (Sec. IV-B); the
-        # matvec is the jnp Block-ELL oracle — adjoint traffic is a small
+        # matvec is the jnp Block-CSR oracle — adjoint traffic is a small
         # fraction of forward traffic, so it does not warrant a kernel.
         a = jnp.asarray(a)
         squeeze = a.ndim == 2  # (eta, N) -> signals are 1-D
@@ -400,13 +402,13 @@ class BsrBackend:
         ap = ap.at[:, : state.n].set(a3[:, state.perm])
         if isinstance(state, _BsrMultiState):
             out = chebyshev.cheb_adjoint_apply_joint(
-                [self._bell_matvec(b, state.n_pad) for b in state.bells],
+                [self._bsr_matvec(m, state.n_pad) for m in state.mats],
                 ap,
                 filt.coeffs,
                 filt.shift_lmaxes,
             )
         else:
-            mv = self._bell_matvec(state.bell, state.n_pad)
+            mv = self._bsr_matvec(state.mat, state.n_pad)
             out = chebyshev.cheb_adjoint_apply(
                 mv, ap, filt.coeffs, filt.lmax
             )

@@ -2,13 +2,13 @@
 
 One filtering primitive, many execution substrates (DESIGN.md Sec. 6): a
 backend packages how ``Phi~ f`` / ``Phi~* a`` are evaluated — dense matmul,
-fused Pallas Block-ELL kernel, or a ``shard_map``-distributed matvec — behind
+fused Pallas Block-CSR kernel, or a ``shard_map``-distributed matvec — behind
 a small protocol, so new substrates (GPU sparse, multi-host) drop in by
 registering one class and never touch callers.
 
 The protocol mirrors the paper's separation of concerns: the *spectral* data
 (coefficients, lmax, shift structure) lives on the filter; the
-*graph-operator* data (dense Laplacian, Block-ELL tiles, partition plans) is
+*graph-operator* data (dense Laplacian, Block-CSR tiles, partition plans) is
 backend state built once by ``prepare`` and cached on the filter per backend.
 
 What a backend can do is declared in one frozen

@@ -8,7 +8,7 @@ kernel fits (DESIGN.md Sec. 6.3).
 
 The decisions this module makes:
 
-* ``block_size`` — the Block-ELL tile edge (:func:`choose_block_size`),
+* ``block_size`` — the Block-CSR tile edge (:func:`choose_block_size`),
   from the Laplacian's own tile occupancy and a cost model, fitted on the
   chip, of the stepwise chain and of what the tiles add to set-up,
 * ``f_tile``  — the F-dimension tile both kernels pipeline over,
@@ -27,7 +27,7 @@ import math
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.ref import tile_mask
+from repro.kernels.ref import stored_tiles, tile_mask
 
 __all__ = [
     "BLOCK_CANDIDATES",
@@ -50,10 +50,10 @@ VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 BLOCK_CANDIDATES = (8, 16, 32, 64, 128, 256)
 
 # The stepwise chain's cost model (``_order_s``): one grid step of
-# ``cheb_step_pallas`` costs STEP_S, plus the larger of its DMA (the tile
-# and the gathered (block, f_tile) slab at HBM_BYTES_PER_S, the v5e's
-# published bandwidth) and its HIGHEST-precision contraction (at
-# MXU_F32_FLOPS_PER_S). STEP_S and MXU_F32_FLOPS_PER_S are fitted to a
+# ``cheb_step_pallas``, one stored tile, costs STEP_S, plus the larger of
+# its DMA (the tile and the gathered (block, f_tile) slab at
+# HBM_BYTES_PER_S, the v5e's published bandwidth) and its
+# HIGHEST-precision contraction (at MXU_F32_FLOPS_PER_S). STEP_S and MXU_F32_FLOPS_PER_S are fitted to a
 # TPU v5e sweep of block_size 8..256 at the 16,384-sensor field, F=128:
 # 0.223, 0.238, 0.260, 0.300, 0.366 and 0.711 us a step, each within 3%
 # of the model (PERF.md, Findings).
@@ -72,7 +72,7 @@ MXU_F32_FLOPS_PER_S = 34e12
 SETUP_S_PER_TILE_BYTE = 4.0e-8
 # Applies of the filter that the tiles' set-up is weighed against. Both
 # this and the slope above rest on that one field; at it the pick is 128
-# from about 460 to 1,840 applies, 64 below and 256 above.
+# from about 270 to 4,800 applies, 64 below and 256 above.
 AMORTIZE_APPLIES = 1_000
 
 # Preferred f_tile per (block_size bucket, dtype bucket), widest first:
@@ -108,7 +108,7 @@ class Tiling:
 
 @dataclasses.dataclass(frozen=True)
 class BlockChoice:
-    """The Block-ELL block size :func:`choose_block_size` picked.
+    """The Block-CSR block size :func:`choose_block_size` picked.
 
     Attributes:
       block_size: the tile edge.
@@ -144,8 +144,7 @@ def union_vmem_bytes(
     n: int,
     f_tile: int,
     eta: int,
-    n_rows: int,
-    k_max: int,
+    n_tiles: int,
     block: int,
     dtype=jnp.float32,
     *,
@@ -157,19 +156,20 @@ def union_vmem_bytes(
     The pipelined operands — the resident Laplacian tiles, the input
     tile and the (eta, N, f_tile) output tile — are double-buffered by
     Pallas, so they count twice; the scratch counts once: two Krylov
-    (ping/pong) buffers in ``krylov_dtype`` and the (eta, N, f_tile) f32
-    accumulators. ``krylov_dtype="bfloat16"`` halves the Krylov term,
-    which is why the bf16 mode raises the fuse threshold in
-    :func:`select_tiling`.
+    (ping/pong) buffers in ``krylov_dtype``, the (eta, N, f_tile) f32
+    accumulators and one block-row of ``L @ T``.
+    ``krylov_dtype="bfloat16"`` halves the Krylov term, which is why the
+    bf16 mode raises the fuse threshold in :func:`select_tiling`.
     """
     pipelined = (
-        _vmem_array_bytes((n_rows, k_max, block, block), dtype)
+        _vmem_array_bytes((n_tiles, block, block), dtype)
         + _vmem_array_bytes((n, f_tile), dtype)
         + _vmem_array_bytes((eta, n, f_tile), dtype)
     )
     scratch = (
         2 * _vmem_array_bytes((n, f_tile), krylov_dtype)
         + _vmem_array_bytes((eta, n, f_tile), jnp.float32)
+        + _vmem_array_bytes((block, f_tile), jnp.float32)
     )
     return 2 * pipelined + scratch
 
@@ -178,8 +178,7 @@ def select_tiling(
     n: int,
     f: int,
     eta: int,
-    n_rows: int,
-    k_max: int,
+    n_tiles: int,
     block: int,
     dtype=jnp.float32,
     vmem_budget: int = VMEM_BUDGET_BYTES,
@@ -194,8 +193,8 @@ def select_tiling(
         Padded signal shape (N, F).
     eta : int
         Number of multipliers in the union.
-    n_rows, k_max, block : int
-        Block-ELL operand shape.
+    n_tiles, block : int
+        Block-CSR operand shape: stored tiles and their edge.
     dtype : jnp dtype
         Signal/Laplacian dtype.
     vmem_budget : int
@@ -215,7 +214,7 @@ def select_tiling(
     tiles = _legal_f_tiles(f, _F_TILE_TABLE.get((block, dt_name), _DEFAULT_LADDER))
     # Widest legal tile that fuses; the widest one when none does.
     for cand in tiles:
-        bytes_ = union_vmem_bytes(n, cand, eta, n_rows, k_max, block, dtype,
+        bytes_ = union_vmem_bytes(n, cand, eta, n_tiles, block, dtype,
                                   krylov_dtype=krylov_dtype)
         if bytes_ <= vmem_budget:
             return Tiling(f_tile=cand, fuse=True, vmem_bytes=bytes_)
@@ -223,16 +222,16 @@ def select_tiling(
         f_tile=tiles[0],
         fuse=False,
         vmem_bytes=union_vmem_bytes(
-            n, tiles[0], eta, n_rows, k_max, block, dtype,
+            n, tiles[0], eta, n_tiles, block, dtype,
             krylov_dtype=krylov_dtype,
         ),
     )
 
 
-def _order_s(block: int, n_rows: int, k_max: int) -> float:
+def _order_s(block: int, n_tiles: int) -> float:
     """Predicted seconds of one order of the stepwise chain
-    (``cheb_step_pallas``) on float32 operands: ``n_rows * k_max`` grid
-    steps, each ``STEP_S + max(dma, mxu)``. HBM tiles the last two dims
+    (``cheb_step_pallas``) on float32 operands: one grid step per stored
+    tile, each ``STEP_S + max(dma, mxu)``. HBM tiles the last two dims
     like VMEM, so a tile below 128 lanes moves as a padded one; the MXU
     contracts over 128-deep passes. The F tile is 128 (``min(F, 128)``:
     F is not known at prepare, and 128 is the width of a full panel)."""
@@ -242,7 +241,7 @@ def _order_s(block: int, n_rows: int, k_max: int) -> float:
     rows = -(-block // 8) * 8
     depth = -(-block // 128) * 128
     mxu = 2 * rows * depth * f_tile / MXU_F32_FLOPS_PER_S
-    return n_rows * k_max * (STEP_S + max(dma, mxu))
+    return n_tiles * (STEP_S + max(dma, mxu))
 
 
 def choose_block_size(mask: np.ndarray, orders: int) -> BlockChoice:
@@ -254,9 +253,9 @@ def choose_block_size(mask: np.ndarray, orders: int) -> BlockChoice:
     the finest candidate (``BLOCK_CANDIDATES[0]``) on the vertex order
     every candidate is built on. A coarse tile holds a nonzero iff one of
     its fine tiles does, so each coarser candidate's occupancy is pooled
-    from it, with no second pass over the Laplacian. Candidates larger
-    than the padded vertex count are not weighed. Ties go to the smaller
-    block.
+    from it, with no second pass over the Laplacian; a candidate costs the
+    tiles it stores (``kernels.ref.stored_tiles``). Candidates larger than
+    the padded vertex count are not weighed. Ties go to the smaller block.
     """
     base = BLOCK_CANDIDATES[0]
     n_pad = mask.shape[0] * base
@@ -264,10 +263,9 @@ def choose_block_size(mask: np.ndarray, orders: int) -> BlockChoice:
     for b in BLOCK_CANDIDATES:
         if b > n_pad:
             break
-        per_row = tile_mask(mask, b // base).sum(axis=1)
-        n_rows, k_max = len(per_row), max(int(per_row.max()), 1)
-        order_s = _order_s(b, n_rows, k_max)
-        setup_s = SETUP_S_PER_TILE_BYTE * 4 * n_rows * k_max * b * b
+        n_tiles = int(stored_tiles(tile_mask(mask, b // base)).sum())
+        order_s = _order_s(b, n_tiles)
+        setup_s = SETUP_S_PER_TILE_BYTE * 4 * n_tiles * b * b
         priced.append((setup_s + AMORTIZE_APPLIES * orders * order_s, b, order_s))
     _, block, order_s = min(priced, key=lambda c: c[0])  # the first on ties
     return BlockChoice(block, order_s, base_order_s=priced[0][2])

@@ -1,4 +1,4 @@
-"""Pallas TPU kernel: Block-ELL Laplacian matvec fused with the Chebyshev
+"""Pallas TPU kernel: Block-CSR Laplacian matvec fused with the Chebyshev
 recurrence step (paper eq. 9) — the compute hot-spot of the whole method.
 
 Every Chebyshev order is ``T_k = (2/a) L T_{k-1} - 2 T_{k-1} - T_{k-2}``.
@@ -8,19 +8,18 @@ whole step: one pass over the Laplacian tiles, the affine combine applied in
 VMEM before the single store of ``T_k``.
 
 TPU adaptation (DESIGN.md Sec. 3): the GPU-idiomatic CSR gather-per-row is
-replaced by Block-ELL — spatially-ordered vertices give few dense
-``(block x block)`` tiles per block-row; each tile multiply is an MXU
-contraction against an ``F``-wide signal batch. The data-dependent tile
-gather uses **scalar prefetch**: block-column indices live in SMEM and feed
-the BlockSpec index_map, so Pallas pipelines the HBM->VMEM tile streams
-without kernel-visible gathers. The indices are prefetched flat,
-``(n_rows * k_max,)``: SMEM pads the last dim of a 2-D operand to 128
-words, so a 2-D ``(n_rows, k_max)`` table would cost 512 B per block-row
-whatever ``k_max`` is, and 2048 block-rows would fill the whole 1 MiB.
+replaced by Block-CSR — ordered vertices give few dense ``(block x block)``
+tiles; each tile multiply is an MXU contraction against an ``F``-wide
+signal batch. The data-dependent tile gather uses **scalar prefetch**: the
+block-row and block-column of every stored tile live in SMEM and feed the
+BlockSpec index_maps, so Pallas pipelines the HBM->VMEM tile streams
+without kernel-visible gathers. Both tables are 1-D, ``(n_tiles,)``:
+SMEM pads the last dim of a 2-D operand to 128 words.
 
-Grid: ``(F_tiles, n_block_rows, k_max)`` with the sparse-column loop
-innermost — the output block revisits k_max times and accumulates in VMEM
-(init at j == 0, combine at j == k_max - 1).
+Grid: ``(F_tiles, n_tiles)``, one step per stored tile, tiles sorted by
+block-row. The output block ``(rows[t], fi)`` is revisited over a row's
+consecutive steps and accumulates in VMEM (init on the row's first tile,
+combine on its last); a row's tile count costs nothing on another row.
 """
 
 from __future__ import annotations
@@ -38,25 +37,35 @@ __all__ = ["cheb_step_pallas", "cheb_union_pallas"]
 _F32 = jax.lax.Precision.HIGHEST
 
 
+def _row_ends(rows_ref, t, n_tiles: int):
+    """Whether stored tile ``t`` opens its block-row, and whether it
+    closes it (tiles are sorted by block-row)."""
+    row = rows_ref[t]
+    first = (t == 0) | (rows_ref[jnp.maximum(t - 1, 0)] != row)
+    last = (t == n_tiles - 1) | (rows_ref[jnp.minimum(t + 1, n_tiles - 1)] != row)
+    return first, last
+
+
 def _cheb_step_kernel(
     # scalar-prefetch operands
-    cols_ref,  # (n_rows * k_max,) int32, SMEM
+    rows_ref,  # (n_tiles,) int32, SMEM
+    cols_ref,  # (n_tiles,) int32, SMEM
     # tensor operands
-    blocks_ref,  # (1, 1, B, B)    Laplacian tile for (i, j)
-    t1g_ref,  # (B, FT)            gathered T_{k-1}[cols[i, j]]
-    t1s_ref,  # (B, FT)            aligned  T_{k-1}[i]
-    t2s_ref,  # (B, FT)            aligned  T_{k-2}[i]
-    out_ref,  # (B, FT)            T_k[i]
-    acc_ref,  # (B, FT) f32 VMEM scratch — accumulator survives the j loop
+    blocks_ref,  # (1, B, B)         Laplacian tile t
+    t1g_ref,  # (B, FT)            gathered T_{k-1}[cols[t]]
+    t1s_ref,  # (B, FT)            aligned  T_{k-1}[rows[t]]
+    t2s_ref,  # (B, FT)            aligned  T_{k-2}[rows[t]]
+    out_ref,  # (B, FT)            T_k[rows[t]]
+    acc_ref,  # (B, FT) f32 VMEM scratch — accumulator survives the row's tiles
     *,
-    k_max: int,
+    n_tiles: int,
     ca: float,
     cb: float,
     cc: float,
 ):
-    j = pl.program_id(2)
+    first, last = _row_ends(rows_ref, pl.program_id(1), n_tiles)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -65,13 +74,13 @@ def _cheb_step_kernel(
     # HIGHEST: Mosaic's default f32 contraction rounds the operands to
     # bf16 (4e-3 relative error per apply on a v5e).
     acc_ref[...] += jnp.dot(
-        blocks_ref[0, 0].astype(jnp.float32),
+        blocks_ref[0].astype(jnp.float32),
         t1g_ref[...].astype(jnp.float32),
         preferred_element_type=jnp.float32,
         precision=_F32,
     )
 
-    @pl.when(j == k_max - 1)
+    @pl.when(last)
     def _combine():
         # Fused affine recurrence: T_k = ca * (L t1) + cb * t1 + cc * t2,
         # combined in f32 and cast once on the single store of T_k.
@@ -88,6 +97,7 @@ def _cheb_step_kernel(
 )
 def cheb_step_pallas(
     blocks: jax.Array,
+    rows: jax.Array,
     cols: jax.Array,
     t1: jax.Array,
     t2: jax.Array,
@@ -97,13 +107,14 @@ def cheb_step_pallas(
     f_tile: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """One fused Chebyshev recurrence step on Block-ELL operands.
+    """One fused Chebyshev recurrence step on Block-CSR operands.
 
     Args:
-      blocks: (n_rows, k_max, B, B) Laplacian tiles.
-      cols:   (n_rows, k_max) int32 block-column ids (padding: col 0 +
-        zero tile).
-      t1: (N, F) ``T_{k-1}`` with N = n_rows * B.
+      blocks: (n_tiles, B, B) Laplacian tiles, sorted by block-row.
+      rows:   (n_tiles,) int32 block-row of each tile; every block-row of
+        ``t1`` holds at least one tile (``kernels/ref.py``).
+      cols:   (n_tiles,) int32 block-column of each tile.
+      t1: (N, F) ``T_{k-1}`` with N a multiple of B.
       t2: (N, F) ``T_{k-2}`` (pass t1 when ``first=True``; ignored).
       alpha: lmax / 2 spectrum shift.
       first: compute ``T_1 = (L - a I) f / a`` instead of the k >= 2 step.
@@ -112,10 +123,10 @@ def cheb_step_pallas(
 
     Returns: (N, F) ``T_k``.
     """
-    n_rows, k_max, b, b2 = blocks.shape
+    n_tiles, b, b2 = blocks.shape
     assert b == b2, blocks.shape
     n, f = t1.shape
-    assert n == n_rows * b, (t1.shape, blocks.shape)
+    assert n % b == 0, (t1.shape, blocks.shape)
     ft = f_tile or min(f, 128)
     assert f % ft == 0, (f, ft)
 
@@ -125,35 +136,36 @@ def cheb_step_pallas(
         ca, cb, cc = 2.0 / alpha, -2.0, -1.0
 
     kernel = functools.partial(
-        _cheb_step_kernel, k_max=k_max, ca=ca, cb=cb, cc=cc
+        _cheb_step_kernel, n_tiles=n_tiles, ca=ca, cb=cb, cc=cc
     )
 
-    grid = (f // ft, n_rows, k_max)
+    def row(fi, t, rows, cols):
+        return rows[t], fi
+
+    grid = (f // ft, n_tiles)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(
-                    (1, 1, b, b), lambda fi, i, j, cols: (i, j, 0, 0)
-                ),
+                pl.BlockSpec((1, b, b), lambda fi, t, rows, cols: (t, 0, 0)),
                 pl.BlockSpec(  # gathered t1 rows via scalar-prefetched cols
-                    (b, ft), lambda fi, i, j, cols: (cols[i * k_max + j], fi)
+                    (b, ft), lambda fi, t, rows, cols: (cols[t], fi)
                 ),
-                pl.BlockSpec((b, ft), lambda fi, i, j, cols: (i, fi)),
-                pl.BlockSpec((b, ft), lambda fi, i, j, cols: (i, fi)),
+                pl.BlockSpec((b, ft), row),
+                pl.BlockSpec((b, ft), row),
             ],
-            out_specs=pl.BlockSpec((b, ft), lambda fi, i, j, cols: (i, fi)),
+            out_specs=pl.BlockSpec((b, ft), row),
             scratch_shapes=[pltpu.VMEM((b, ft), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n, f), t1.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
         name="cheb_bsr_step",
-    )(cols.reshape(-1), blocks, t1, t1, t2)
+    )(rows, cols, blocks, t1, t1, t2)
 
 
 # ---------------------------------------------------------------------------
@@ -162,85 +174,91 @@ def cheb_step_pallas(
 
 
 def _cheb_union_kernel(
-    # scalar-prefetch operand
-    cols_ref,  # (n_rows * k_max,) int32, SMEM
+    # scalar-prefetch operands
+    rows_ref,  # (n_tiles,) int32, SMEM
+    cols_ref,  # (n_tiles,) int32, SMEM
     # tensor operands
-    blocks_ref,  # (n_rows, k_max, B, B) — the whole Block-ELL Laplacian
+    blocks_ref,  # (n_tiles, B, B) — the whole Block-CSR Laplacian
     f_ref,  # (N, FT)                     input signal tile (= T_0)
     out_ref,  # (eta, N, FT)              combined outputs, one per multiplier
     ta_ref,  # (N, FT) VMEM scratch — T_k ping buffer (krylov_dtype)
     tb_ref,  # (N, FT) VMEM scratch — T_k pong buffer (krylov_dtype)
     acc_ref,  # (eta, N, FT) f32 VMEM scratch — eq. 11 accumulators
+    row_ref,  # (B, FT) f32 VMEM scratch — one block-row of L @ T_{k-1}
     *,
     coeffs: tuple[tuple[float, ...], ...],
     alpha: float,
-    n_rows: int,
-    k_max: int,
+    n_tiles: int,
     block: int,
-    ft: int,
 ):
     """Run eq. 9 + eq. 11 entirely in VMEM.
 
     The recurrence alternates between two (N, FT) scratch buffers; the j-th
-    accumulator picks up ``c_{j,k} * T_k`` inside the same row loop that
-    produces ``T_k``, so no order's ``T_k`` is ever stored to HBM. The
-    in-place pong write is safe: row ``i`` of ``T_{k-2}`` is consumed
-    (aligned read) in the same loop iteration that overwrites it, and the
-    gathered operand is always the *other* buffer (``T_{k-1}``).
+    accumulator picks up ``c_{j,k} * T_k`` inside the same pass over the
+    tiles that produces ``T_k``, so no order's ``T_k`` is ever stored to
+    HBM. The in-place pong write is safe: block-row ``r`` of ``T_{k-2}`` is
+    consumed (aligned read) when row ``r`` of ``T_k`` overwrites it, and
+    the gathered operand is always the *other* buffer (``T_{k-1}``).
 
     Krylov precision: the ping/pong buffers carry ``krylov_dtype`` (the
     pallas_call picks the scratch dtype); every step still computes in f32
     and the accumulators pick up the *pre-rounding* f32 ``T_k`` — only the
     value the next recurrence step reads back is rounded. With f32 buffers
-    every cast is a no-op, so the f32 path is bit-identical to the
-    pre-``krylov_dtype`` kernel; bf16 buffers halve the Krylov VMEM
-    footprint (see ``autotune.union_vmem_bytes``).
+    every cast is a no-op; bf16 buffers halve the Krylov VMEM footprint
+    (see ``autotune.union_vmem_bytes``).
     """
-    eta = len(coeffs)
     order = len(coeffs[0]) - 1
     f32 = jnp.float32
 
-    def spmv_row(src_ref, i):
-        """(L @ src)[i-th block row] via scalar-prefetched tile gather."""
-        acc = jnp.zeros((block, ft), f32)
-        for j in range(k_max):
-            c = cols_ref[i * k_max + j]
-            seg = src_ref[pl.ds(c * block, block), :]
-            acc += jnp.dot(
-                blocks_ref[i, j].astype(f32), seg.astype(f32),
+    def sweep(src_ref, finish):
+        """One pass over the stored tiles: ``L @ src`` a block-row at a
+        time, handed to ``finish(rows, lx)`` on the row's last tile."""
+
+        def tile(t, _):
+            first, last = _row_ends(rows_ref, t, n_tiles)
+
+            @pl.when(first)
+            def _init():
+                row_ref[...] = jnp.zeros_like(row_ref)
+
+            seg = src_ref[pl.ds(cols_ref[t] * block, block), :]
+            row_ref[...] += jnp.dot(
+                blocks_ref[t].astype(f32), seg.astype(f32),
                 preferred_element_type=f32, precision=_F32,
             )
-        return acc
+
+            @pl.when(last)
+            def _finish():
+                finish(pl.ds(rows_ref[t] * block, block), row_ref[...])
+
+            return 0
+
+        jax.lax.fori_loop(0, n_tiles, tile, 0, unroll=False)
 
     # ---- k = 0, 1:  T_1 = (L - aI) f / a, accumulators initialised -------
-    def init_row(i, _):
-        sl = pl.ds(i * block, block)
+    def init_row(sl, lx):
         t0 = f_ref[sl, :].astype(f32)
-        t1 = spmv_row(f_ref, i) / alpha - t0
+        t1 = lx / alpha - t0
         ta_ref[sl, :] = t1.astype(ta_ref.dtype)
-        for j in range(eta):
-            acc_ref[j, sl, :] = coeffs[j][0] * 0.5 * t0 + coeffs[j][1] * t1
-        return 0
+        for j, c in enumerate(coeffs):
+            acc_ref[j, sl, :] = c[0] * 0.5 * t0 + c[1] * t1
 
-    jax.lax.fori_loop(0, n_rows, init_row, 0, unroll=False)
+    sweep(f_ref, init_row)
 
     # ---- k >= 2: ping-pong the recurrence, combine in the same pass ------
     def make_step(k, src1_ref, src0_ref, dst_ref):
         # src0 may alias dst: T_k overwrites T_{k-2} row by row (see above).
-        def step_row(i, _):
-            sl = pl.ds(i * block, block)
-            lx = spmv_row(src1_ref, i)
+        def step_row(sl, lx):
             t_new = (
                 (2.0 / alpha) * lx
                 - 2.0 * src1_ref[sl, :].astype(f32)
                 - src0_ref[sl, :].astype(f32)
             )
             dst_ref[sl, :] = t_new.astype(dst_ref.dtype)
-            for j in range(eta):
-                acc_ref[j, sl, :] += coeffs[j][k] * t_new
-            return 0
+            for j, c in enumerate(coeffs):
+                acc_ref[j, sl, :] += c[k] * t_new
 
-        jax.lax.fori_loop(0, n_rows, step_row, 0, unroll=False)
+        sweep(src1_ref, step_row)
 
     for k in range(2, order + 1):
         if k == 2:
@@ -260,6 +278,7 @@ def _cheb_union_kernel(
 )
 def cheb_union_pallas(
     blocks: jax.Array,
+    rows: jax.Array,
     cols: jax.Array,
     f: jax.Array,
     *,
@@ -283,12 +302,12 @@ def cheb_union_pallas(
 
     Parameters
     ----------
-    blocks : jax.Array
-        (n_rows, k_max, B, B) Block-ELL Laplacian tiles.
-    cols : jax.Array
-        (n_rows, k_max) int32 block-column ids (padding: col 0 + zero tile).
+    blocks, rows, cols : jax.Array
+        Block-CSR Laplacian: (n_tiles, B, B) tiles sorted by block-row,
+        and the (n_tiles,) int32 block-row and block-column of each; every
+        block-row holds at least one tile (``kernels/ref.py``).
     f : jax.Array
-        (N, F) signal batch, ``N = n_rows * B``.
+        (N, F) signal batch, N a multiple of B.
     coeffs : tuple of tuples
         Static (eta, M+1) Chebyshev coefficients (hashable: one compile per
         filter, matching the build-once / apply-many filter lifecycle).
@@ -300,7 +319,7 @@ def cheb_union_pallas(
         Run in Pallas interpret mode (CPU validation path).
     krylov_dtype : str
         Static dtype of the two VMEM Krylov (ping/pong) buffers —
-        ``"float32"`` (default, bit-identical to the historic kernel) or
+        ``"float32"`` (default) or
         ``"bfloat16"`` (halves the Krylov working set; the recurrence
         still computes and accumulates in f32, only the stored ``T_k``
         round-trips through bf16).
@@ -310,45 +329,41 @@ def cheb_union_pallas(
     jax.Array
         (eta, N, F) stacked filter outputs.
     """
-    n_rows, k_max, b, b2 = blocks.shape
+    n_tiles, b, b2 = blocks.shape
     assert b == b2, blocks.shape
     n, fdim = f.shape
-    assert n == n_rows * b, (f.shape, blocks.shape)
+    assert n % b == 0, (f.shape, blocks.shape)
     eta = len(coeffs)
     order = len(coeffs[0]) - 1
     assert order >= 1, "need at least order 1 (two coefficients)"
     ft = f_tile or min(fdim, 128)
     assert fdim % ft == 0, (fdim, ft)
-    alpha = lmax / 2.0
     kdt = jnp.dtype(krylov_dtype)
 
     kernel = functools.partial(
         _cheb_union_kernel,
         coeffs=coeffs,
-        alpha=alpha,
-        n_rows=n_rows,
-        k_max=k_max,
+        alpha=lmax / 2.0,
+        n_tiles=n_tiles,
         block=b,
-        ft=ft,
     )
 
     grid = (fdim // ft,)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec(
-                    (n_rows, k_max, b, b), lambda fi, cols: (0, 0, 0, 0)
-                ),
-                pl.BlockSpec((n, ft), lambda fi, cols: (0, fi)),
+                pl.BlockSpec((n_tiles, b, b), lambda fi, rows, cols: (0, 0, 0)),
+                pl.BlockSpec((n, ft), lambda fi, rows, cols: (0, fi)),
             ],
-            out_specs=pl.BlockSpec((eta, n, ft), lambda fi, cols: (0, 0, fi)),
+            out_specs=pl.BlockSpec((eta, n, ft), lambda fi, rows, cols: (0, 0, fi)),
             scratch_shapes=[
                 pltpu.VMEM((n, ft), kdt),
                 pltpu.VMEM((n, ft), kdt),
                 pltpu.VMEM((eta, n, ft), jnp.float32),
+                pltpu.VMEM((b, ft), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((eta, n, fdim), f.dtype),
@@ -357,4 +372,4 @@ def cheb_union_pallas(
         ),
         interpret=interpret,
         name="cheb_bsr_union",
-    )(cols.reshape(-1), blocks, f)
+    )(rows, cols, blocks, f)

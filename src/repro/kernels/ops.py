@@ -21,10 +21,10 @@ import numpy as np
 
 from repro.kernels.autotune import select_tiling
 from repro.kernels.cheb_bsr import cheb_step_pallas, cheb_union_pallas
-from repro.kernels.ref import BlockEll, bsr_from_dense
+from repro.kernels.ref import BlockCsr, bsr_from_dense
 
 __all__ = [
-    "BlockEll",
+    "BlockCsr",
     "bsr_from_dense",
     "cheb_apply_bsr",
     "cheb_apply_bsr_fused",
@@ -33,6 +33,7 @@ __all__ = [
 
 def cheb_apply_bsr_fused(
     blocks: jax.Array,
+    rows: jax.Array,
     cols: jax.Array,
     f: jax.Array,
     coeffs,
@@ -46,8 +47,8 @@ def cheb_apply_bsr_fused(
 
     Parameters
     ----------
-    blocks, cols : jax.Array
-        Block-ELL Laplacian (see ``kernels/ref.py``).
+    blocks, rows, cols : jax.Array
+        Block-CSR Laplacian (see ``kernels/ref.py``).
     f : jax.Array
         (N, F) signal batch.
     coeffs : array-like
@@ -75,13 +76,13 @@ def cheb_apply_bsr_fused(
     )
     kd = jnp.dtype(krylov_dtype or jnp.float32).name
     if f_tile is None:
-        n_rows, k_max, b, _ = blocks.shape
+        n_tiles, b, _ = blocks.shape
         f_tile = select_tiling(
-            f.shape[0], f.shape[1], len(ctup), n_rows, k_max, b, f.dtype,
+            f.shape[0], f.shape[1], len(ctup), n_tiles, b, f.dtype,
             krylov_dtype=kd,
         ).f_tile
     return cheb_union_pallas(
-        blocks, cols, f,
+        blocks, rows, cols, f,
         coeffs=ctup, lmax=float(lmax), f_tile=f_tile, interpret=interpret,
         krylov_dtype=kd,
     )
@@ -92,6 +93,7 @@ def cheb_apply_bsr_fused(
 )
 def cheb_apply_bsr(
     blocks: jax.Array,
+    rows: jax.Array,
     cols: jax.Array,
     f: jax.Array,
     coeffs: jax.Array,
@@ -108,7 +110,7 @@ def cheb_apply_bsr(
     chain remains the large-N fallback and the fused kernel's oracle.
 
     Args:
-      blocks/cols: Block-ELL Laplacian (see kernels/ref.py).
+      blocks/rows/cols: Block-CSR Laplacian (see kernels/ref.py).
       f: (N, F) signal batch (use F >= 8 for MXU efficiency on real TPUs).
       coeffs: (eta, M+1) Chebyshev coefficients.
       lmax: spectrum bound (static).
@@ -123,7 +125,7 @@ def cheb_apply_bsr(
     coeffs = jnp.asarray(coeffs, f.dtype)
     alpha = lmax / 2.0
     step = functools.partial(
-        cheb_step_pallas, blocks, cols,
+        cheb_step_pallas, blocks, rows, cols,
         alpha=alpha, f_tile=f_tile, interpret=interpret,
     )
 

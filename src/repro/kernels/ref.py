@@ -1,7 +1,9 @@
-"""Pure-jnp oracles for the Pallas kernels.
+"""The Block-CSR Laplacian layout, and pure-jnp oracles for the Pallas
+kernels.
 
-The reference implementations operate on the same Block-ELL operands as the
-kernels so the tests compare like-for-like (including padding slots).
+The reference implementations operate on the same Block-CSR operands as
+the kernels so the tests compare like-for-like (including the zero tiles
+that keep empty block-rows written).
 """
 
 from __future__ import annotations
@@ -12,53 +14,51 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["BlockEll", "tile_mask", "bsr_from_dense", "bsr_to_dense",
-           "bsr_matvec_ref", "cheb_step_ref", "cheb_apply_bsr_ref"]
+__all__ = ["BlockCsr", "tile_mask", "permuted_tile_mask", "stored_tiles", "bsr_from_dense",
+           "bsr_to_dense", "bsr_matvec_ref", "cheb_step_ref", "cheb_apply_bsr_ref"]
 
 
 @dataclasses.dataclass(frozen=True)
-class BlockEll:
-    """Block-ELL sparse matrix: fixed number of blocks per block-row.
+class BlockCsr:
+    """Block-CSR sparse matrix: the stored tiles as one list, by row.
 
-    TPU adaptation of CSR (DESIGN.md Sec. 3): vertices are spatially
-    ordered so nonzeros cluster into few dense tiles per row; each tile is
-    an MXU-shaped (block, block) dense matrix. Padding slots have
-    ``cols == 0`` and all-zero blocks, so they contribute nothing.
+    TPU adaptation of CSR (DESIGN.md Sec. 3): vertices are ordered so
+    nonzeros cluster into few dense tiles; each tile is an MXU-shaped
+    (block, block) dense matrix. Tiles are sorted by block-row, then
+    block-column. Every block-row stores at least one tile (an all-zero
+    diagonal tile where it has none), so a kernel that visits the stored
+    tiles writes every row of its output.
 
     Attributes:
-      blocks: (n_rows, k_max, block, block) dense tiles.
-      cols:   (n_rows, k_max) int32 block-column indices.
+      blocks: (n_tiles, block, block) dense tiles.
+      rows:   (n_tiles,) int32 block-row of each tile, nondecreasing.
+      cols:   (n_tiles,) int32 block-column of each tile.
     """
 
     blocks: jax.Array
+    rows: jax.Array
     cols: jax.Array
 
     @property
-    def n_block_rows(self) -> int:
+    def n_tiles(self) -> int:
         return self.blocks.shape[0]
-
-    @property
-    def k_max(self) -> int:
-        return self.blocks.shape[1]
 
     @property
     def block_size(self) -> int:
         return self.blocks.shape[-1]
 
     @property
+    def n_block_rows(self) -> int:
+        return int(self.rows[-1]) + 1
+
+    @property
     def n(self) -> int:
         return self.n_block_rows * self.block_size
 
     @property
-    def nnz_blocks(self) -> int:
-        """True (non-padding) block count."""
-        nz = np.asarray(
-            jnp.any(self.blocks != 0.0, axis=(-1, -2)))
-        return int(nz.sum())
-
-    @property
-    def density(self) -> float:
-        return self.nnz_blocks / (self.n_block_rows**2)
+    def tile_bytes(self) -> int:
+        """Bytes of tiles one pass over the matrix streams."""
+        return self.blocks.size * self.blocks.dtype.itemsize
 
 
 def tile_mask(mat, block_size: int) -> np.ndarray:
@@ -76,57 +76,87 @@ def tile_mask(mat, block_size: int) -> np.ndarray:
     return rows.reshape(nb, nb, block_size).any(axis=2)
 
 
-def bsr_from_dense(mat, block_size: int, dtype=jnp.float32, *,
-                   mask: np.ndarray | None = None) -> BlockEll:
-    """Convert a dense (N, N) matrix to Block-ELL (host-side, build time).
+def permuted_tile_mask(mat, perm: np.ndarray, block_size: int) -> np.ndarray:
+    """``tile_mask(mat[perm][:, perm], block_size)`` from the nonzeros of
+    ``mat`` alone, without the permuted copy."""
+    r, c = np.nonzero(np.asarray(mat))
+    n = len(perm)
+    pos = np.empty(n, dtype=np.int64)
+    pos[perm] = np.arange(n)
+    nb = -(-n // block_size)
+    mask = np.zeros((nb, nb), dtype=bool)
+    mask[pos[r] // block_size, pos[c] // block_size] = True
+    return mask
 
-    N is zero-padded up to a multiple of ``block_size``. ``k_max`` is the
-    max number of nonzero tiles in any block-row (>= 1). ``mask`` is
-    ``tile_mask(mat, block_size)`` where the caller has it already; the
-    matrix is then read only at its nonzero tiles, which are gathered in
-    float64 whatever the matrix's dtype.
+
+def stored_tiles(mask: np.ndarray) -> np.ndarray:
+    """The tiles ``bsr_from_dense`` stores for a tile mask: the nonzero
+    ones, plus the diagonal tile of every block-row that has none."""
+    return mask | np.diag(~mask.any(axis=1))
+
+
+def bsr_from_dense(mat, block_size: int, dtype=jnp.float32, *,
+                   perm: np.ndarray | None = None,
+                   mask: np.ndarray | None = None) -> BlockCsr:
+    """Convert a dense (N, N) matrix to Block-CSR (host-side, build time).
+
+    The matrix is taken in the vertex order ``perm`` (row and column
+    ``i`` of the result are vertex ``perm[i]``; default: as it is) and
+    zero-padded up to a multiple of ``block_size``. ``mask`` is the
+    permuted matrix's ``tile_mask`` where the caller has it already. The
+    matrix is read only at the stored tiles, one block-row at a time, in
+    float32: no permuted or padded copy of it is made.
     """
     m = np.asarray(mat)
+    n, b = m.shape[0], block_size
+    if perm is None:
+        perm = np.arange(n)
     if mask is None:
-        mask = tile_mask(m, block_size)
+        mask = permuted_tile_mask(m, perm, b)
+    rows, cols = np.nonzero(stored_tiles(mask))  # row-major: each row's tiles in column order
     nb = mask.shape[0]
-    pad = nb * block_size - m.shape[0]
-    if pad:
-        m = np.pad(m, ((0, pad), (0, pad)))
-    tiles = m.reshape(nb, block_size, nb, block_size)
-    per_row = mask.sum(axis=1)
-    k_max = max(int(per_row.max()), 1)
-    rows, js = np.nonzero(mask)  # row-major: each row's tiles in column order
-    slots = np.arange(len(rows)) - np.repeat(np.cumsum(per_row) - per_row, per_row)
-    blocks = np.zeros((nb, k_max, block_size, block_size))
-    cols = np.zeros((nb, k_max), dtype=np.int32)
-    blocks[rows, slots] = tiles[rows, :, js, :]
-    cols[rows, slots] = js
-    return BlockEll(jnp.asarray(blocks, dtype), jnp.asarray(cols))
+    at = np.full(nb * b, n)  # the vertex at each padded position; n past the end
+    at[:n] = perm
+    real = at < n
+    ptr = np.searchsorted(rows, np.arange(nb + 1))
+    blocks = np.zeros((len(rows), b, b), np.float32)
+    for r in range(nb):
+        lo, hi = ptr[r], ptr[r + 1]
+        ri = at[r * b:(r + 1) * b]
+        ci = (cols[lo:hi, None] * b + np.arange(b)).ravel()
+        stripe = np.zeros((b, (hi - lo) * b), np.float32)
+        stripe[np.ix_(real[r * b:(r + 1) * b], real[ci])] = m[np.ix_(ri[ri < n], at[ci][real[ci]])]
+        blocks[lo:hi] = stripe.reshape(b, hi - lo, b).transpose(1, 0, 2)
+    return BlockCsr(jnp.asarray(blocks, dtype), jnp.asarray(rows, jnp.int32),
+                    jnp.asarray(cols, jnp.int32))
 
 
-def bsr_to_dense(bell: BlockEll) -> jax.Array:
+def bsr_to_dense(bsr: BlockCsr) -> jax.Array:
     """Densify (oracle / debugging)."""
-    nb, k_max, b, _ = bell.blocks.shape
-    out = jnp.zeros((nb, nb, b, b), bell.blocks.dtype)
-    rows = jnp.repeat(jnp.arange(nb), k_max)
-    cols = bell.cols.reshape(-1)
-    out = out.at[rows, cols].add(bell.blocks.reshape(nb * k_max, b, b))
+    nb, b = bsr.n_block_rows, bsr.block_size
+    out = jnp.zeros((nb, nb, b, b), bsr.blocks.dtype)
+    out = out.at[bsr.rows, bsr.cols].add(bsr.blocks)
     return out.transpose(0, 2, 1, 3).reshape(nb * b, nb * b)
 
 
-def bsr_matvec_ref(bell: BlockEll, x: jax.Array) -> jax.Array:
-    """Oracle ``L @ x`` from Block-ELL operands. x: (N, F)."""
-    nb, k_max, b, _ = bell.blocks.shape
+def _tile_products(bsr: BlockCsr, x: jax.Array) -> jax.Array:
+    """(N, F) ``L @ x`` in f32: each tile against its gathered column
+    block, summed into its block-row."""
+    b = bsr.block_size
+    nb = x.shape[0] // b
     xb = x.reshape(nb, b, -1)
-    gathered = xb[bell.cols]  # (nb, k_max, b, F)
-    out = jnp.einsum("rkij,rkjf->rif", bell.blocks, gathered,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(x.shape).astype(x.dtype)
+    prods = jnp.einsum("tij,tjf->tif", bsr.blocks, xb[bsr.cols],
+                       preferred_element_type=jnp.float32)
+    return jax.ops.segment_sum(prods, bsr.rows, num_segments=nb).reshape(x.shape)
+
+
+def bsr_matvec_ref(bsr: BlockCsr, x: jax.Array) -> jax.Array:
+    """Oracle ``L @ x`` from Block-CSR operands. x: (N, F)."""
+    return _tile_products(bsr, x).astype(x.dtype)
 
 
 def cheb_step_ref(
-    bell: BlockEll,
+    bsr: BlockCsr,
     t1: jax.Array,
     t2: jax.Array,
     alpha: float,
@@ -141,10 +171,7 @@ def cheb_step_ref(
     Matches the kernel's numerics: f32 accumulate + f32 combine, one final
     cast to the input dtype.
     """
-    nb, k_max, b, _ = bell.blocks.shape
-    xb = t1.reshape(nb, b, -1)
-    lv = jnp.einsum("rkij,rkjf->rif", bell.blocks, xb[bell.cols],
-                    preferred_element_type=jnp.float32).reshape(t1.shape)
+    lv = _tile_products(bsr, t1)
     t1f = t1.astype(jnp.float32)
     if first:
         out = lv / alpha - t1f
@@ -153,9 +180,9 @@ def cheb_step_ref(
     return out.astype(t1.dtype)
 
 
-def cheb_apply_bsr_ref(bell, f, coeffs, lmax):
-    """Oracle for the full union apply on Block-ELL operands."""
+def cheb_apply_bsr_ref(bsr, f, coeffs, lmax):
+    """Oracle for the full union apply on Block-CSR operands."""
     from repro.core import chebyshev
 
     return chebyshev.cheb_apply(
-        lambda v: bsr_matvec_ref(bell, v), f, coeffs, lmax)
+        lambda v: bsr_matvec_ref(bsr, v), f, coeffs, lmax)

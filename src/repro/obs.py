@@ -1,6 +1,6 @@
 """Program spans and compile counters, on the device trace's clock.
 
-One module, two instruments:
+One module, three instruments:
 
 * **Spans.** ``with obs.span("halo.gather", apply=7): ...`` marks one
   layer boundary. While recording is on (``enable()``), a span enters
@@ -23,6 +23,11 @@ One module, two instruments:
   read), so a backend compile during which the cache reported a hit is
   kept as a ``cache_load``, never as both.
 
+* **Layout record**, always on: the operand layout a backend prepared
+  last, as plain numbers (``record_layout``): the ``bsr`` backend's
+  block, block-rows, stored tiles and tile bytes, beside the vertex count
+  and nonzeros of the Laplacian it tiled.
+
 ``snapshot()`` returns all of it as plain data and ``diff(after, before)``
 what happened between two snapshots. Nothing is written to disk.
 """
@@ -41,7 +46,8 @@ try:  # where ``jax.profiler.start_trace`` keeps its session
 except ImportError:  # pragma: no cover
     _jax_profiler = None
 
-__all__ = ["span", "enable", "disable", "enabled", "snapshot", "diff", "compile_counts"]
+__all__ = ["span", "enable", "disable", "enabled", "snapshot", "diff", "compile_counts",
+           "record_layout"]
 
 #: Compile-counter event kinds.
 KINDS = ("trace", "compile", "cache_load")
@@ -61,6 +67,7 @@ _agg: dict[str, list] = {}  # name -> [count, total_ns, self_ns]
 # kind -> [n, s, by fun, n profiled, s profiled]
 _counts = {k: [0, 0.0, collections.Counter(), 0, 0.0] for k in KINDS}
 _events: collections.deque = collections.deque(maxlen=_KEEP_EVENTS)
+_layout: dict | None = None
 
 
 def _stack() -> list:
@@ -203,6 +210,16 @@ def compile_counts(kind: str = "trace") -> dict[str, int]:
         return dict(_counts[kind][2])
 
 
+# -- the layout record ------------------------------------------------------
+
+
+def record_layout(**fields) -> None:
+    """Keep ``fields`` (plain numbers) as the layout prepared last."""
+    global _layout
+    with _lock:
+        _layout = dict(fields)
+
+
 # -- reading ---------------------------------------------------------------
 
 
@@ -213,6 +230,7 @@ def snapshot() -> dict:
     {"n", "s", "by_fun", "profiled_n", "profiled_s"} (the last two: those
     while a ``jax.profiler`` trace was recorded); ``events``: the newest
     compile-counter events (kind, fun, s, span, profiled, start_ns, t_ns);
+    ``layout``: the layout prepared last (``record_layout``), or None;
     ``t_ns``: when the snapshot was taken (``perf_counter_ns``).
     """
     with _lock:
@@ -224,11 +242,13 @@ def snapshot() -> dict:
                              "profiled_n": pn, "profiled_s": ps}
                          for k, (n, s, by, pn, ps) in _counts.items()},
             "events": list(_events),
+            "layout": None if _layout is None else dict(_layout),
         }
 
 
 def diff(after: dict, before: dict) -> dict:
-    """What happened between two snapshots, in the snapshot's shape."""
+    """What happened between two snapshots, in the snapshot's shape (the
+    layout is the later snapshot's)."""
     t0 = before["t_ns"]
     spans = {}
     for k, a in after["spans"].items():
@@ -246,4 +266,5 @@ def diff(after: dict, before: dict) -> dict:
         "spans": spans,
         "compiles": compiles,
         "events": [e for e in after["events"] if e["t_ns"] > t0],
+        "layout": after["layout"],
     }

@@ -1,4 +1,4 @@
-"""The Block-ELL block size the ``bsr`` backend chooses from the
+"""The Block-CSR block size the ``bsr`` backend chooses from the
 Laplacian's tile occupancy (``kernels/autotune.py``), on the CPU."""
 
 from __future__ import annotations
@@ -69,11 +69,10 @@ def test_pooled_occupancy_matches_bsr_from_dense(seed, n):
     for b in autotune.BLOCK_CANDIDATES:
         pooled = ref.tile_mask(fine, b // 8)
         np.testing.assert_array_equal(pooled, ref.tile_mask(lap, b))
-        bell = ref.bsr_from_dense(lap, b)
-        per_row = pooled.sum(axis=1)
-        assert (max(int(per_row.max()), 1), int(per_row.sum())) == (
-            bell.k_max, bell.nnz_blocks), b
-        np.testing.assert_array_equal(np.asarray(ref.bsr_to_dense(bell))[:n, :n],
+        mat = ref.bsr_from_dense(lap, b)
+        assert mat.n_tiles == int(ref.stored_tiles(pooled).sum()), b
+        assert mat.n_block_rows == len(pooled)
+        np.testing.assert_array_equal(np.asarray(ref.bsr_to_dense(mat))[:n, :n],
                                       lap.astype(np.float32))
 
 
@@ -85,9 +84,9 @@ def _band(nb: int) -> np.ndarray:
 
 def _scatter(nb: int) -> np.ndarray:
     """The diagonal and one far tile a row: coarser tilings pool only the
-    diagonal, so their tiles a row grow with the block, and the grid steps
-    (4,096, 3,072, 2,560, 2,304, 2,176, 2,112 at blocks 8 ... 256 for
-    ``nb`` 2,048) fall less than each step's cost grows beyond 32."""
+    diagonal, so their tiles a row grow with the block, and the stored
+    tiles (4,092, 3,068, 2,556, 2,292, 2,156, 2,076 at blocks 8 ... 256
+    for ``nb`` 2,048) fall less than each step's cost grows beyond 32."""
     mask = np.eye(nb, dtype=bool)
     mask[np.arange(nb), (37 * np.arange(nb) + nb // 2) % nb] = True
     return mask
@@ -117,27 +116,28 @@ def test_explicit_block_size_is_honoured(recording, tmp_path):
     be = get_backend("bsr")
     states = []
     (args,) = _block_ell_spans(lambda: states.append(be.prepare(filt, block_size=16)), tmp_path)
-    assert states[0].bell.block_size == 16
+    assert states[0].mat.block_size == 16
     assert args["chosen"] == "user" and args["block_size"] == 16
-    assert args["k_max"] == states[0].bell.k_max
+    assert args["slots"] == states[0].mat.n_tiles
 
 
 def test_block_ell_span_carries_the_choice(recording, tmp_path):
     filt = _filter(_field(6, 1024))
     states = []
     (args,) = _block_ell_spans(lambda: states.append(get_backend("bsr").prepare(filt)), tmp_path)
-    bell = states[0].bell
+    mat = states[0].mat
+    nonzero = int(np.any(np.asarray(mat.blocks) != 0, axis=(1, 2)).sum())
     assert args["chosen"] == "model"
-    assert args["block_size"] == bell.block_size in autotune.BLOCK_CANDIDATES
-    assert args["k_max"] == bell.k_max
-    assert args["fill"] == pytest.approx(bell.nnz_blocks / (bell.n_block_rows * bell.k_max))
+    assert args["block_size"] == mat.block_size in autotune.BLOCK_CANDIDATES
+    assert args["slots"] == mat.n_tiles and args["tiles"] == nonzero
+    assert args["fill"] == pytest.approx(nonzero / mat.n_tiles)
     assert 0.0 < args["order_ms"] <= args["base_order_ms"]
 
 
 def test_bsr_at_the_chosen_block_matches_dense():
     g = _field(7, 600)
     filt = _filter(g)
-    assert filt.prepare_backend("bsr").bell.block_size > 8
+    assert filt.prepare_backend("bsr").mat.block_size > 8
     f = np.random.default_rng(7).normal(size=(600, 8)).astype(np.float32)
     got = filt.apply(f, backend="bsr", interpret=True)
     want = filt.apply(f, backend="dense")

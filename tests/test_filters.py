@@ -217,21 +217,21 @@ def test_fused_union_kernel_is_one_pallas_call(sensor_setting):
     stepwise chain executes one per order (T_k HBM round-trips)."""
     _, filt, f = sensor_setting
     state = get_backend("bsr").prepare(filt)
-    bell = state.bell
+    mat = state.mat
     fp = jnp.zeros((state.n_pad, 8), f.dtype).at[: state.n].set(
         f[state.perm])
 
     fused_jaxpr = jax.make_jaxpr(
-        lambda b, c, x: kops.cheb_apply_bsr_fused(
-            b, c, x, filt.coeffs, filt.lmax, interpret=True)
-    )(bell.blocks, bell.cols, fp)
+        lambda b, r, c, x: kops.cheb_apply_bsr_fused(
+            b, r, c, x, filt.coeffs, filt.lmax, interpret=True)
+    )(mat.blocks, mat.rows, mat.cols, fp)
     assert str(fused_jaxpr).count("pallas_call") == 1
 
     step_jaxpr = jax.make_jaxpr(
-        lambda b, c, x: kops.cheb_apply_bsr(
-            b, c, x, jnp.asarray(filt.coeffs, x.dtype), filt.lmax,
+        lambda b, r, c, x: kops.cheb_apply_bsr(
+            b, r, c, x, jnp.asarray(filt.coeffs, x.dtype), filt.lmax,
             interpret=True)
-    )(bell.blocks, bell.cols, fp)
+    )(mat.blocks, mat.rows, mat.cols, fp)
     # first-order call + the scan-body call (executed order-1 times).
     assert str(step_jaxpr).count("pallas_call") >= 2
 
@@ -247,9 +247,9 @@ def test_fused_matches_stepwise(sensor_setting):
 def test_autotune_falls_back_when_vmem_exceeded():
     from repro.kernels.autotune import select_tiling
 
-    small = select_tiling(96, 8, 2, 12, 6, 8, jnp.float32)
+    small = select_tiling(96, 8, 2, 72, 8, jnp.float32)
     assert small.fuse and small.f_tile == 8
-    huge = select_tiling(2**20, 512, 8, 2**17, 16, 8, jnp.float32,
+    huge = select_tiling(2**20, 512, 8, 2**21, 8, jnp.float32,
                          vmem_budget=1 << 20)
     assert not huge.fuse
 

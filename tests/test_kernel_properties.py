@@ -15,25 +15,35 @@ from repro.kernels.cheb_bsr import cheb_step_pallas, cheb_union_pallas
 from repro.core import chebyshev
 
 
+def _random_bsr(key, n_rows, density, block, scale=1.0):
+    """Block-CSR over a random tile mask of about ``density``, with the
+    zero tiles that keep empty rows."""
+    km, kb = jax.random.split(key)
+    mask = np.asarray(jax.random.uniform(km, (n_rows, n_rows)) < density)
+    rows, cols = np.nonzero(ref.stored_tiles(mask))
+    blocks = scale * jax.random.normal(kb, (len(rows), block, block))
+    blocks = blocks * jnp.asarray(mask[rows, cols], blocks.dtype)[:, None, None]
+    return ref.BlockCsr(blocks, jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32))
+
+
 @hypothesis.settings(max_examples=20, deadline=None)
 @hypothesis.given(
     n_rows=st.integers(2, 8),
-    k_max=st.integers(1, 4),
+    density=st.floats(0.0, 1.0),
     block=st.sampled_from([8, 16]),
     f=st.sampled_from([8, 16]),
     seed=st.integers(0, 2**30),
 )
-def test_cheb_step_property(n_rows, k_max, block, f, seed):
-    """Property: kernel == oracle for arbitrary Block-ELL structures."""
+def test_cheb_step_property(n_rows, density, block, f, seed):
+    """Property: kernel == oracle for arbitrary Block-CSR structures."""
     key = jax.random.PRNGKey(seed)
     kb, k1, k2 = jax.random.split(key, 3)
-    blocks = jax.random.normal(kb, (n_rows, k_max, block, block))
-    cols = jax.random.randint(k1, (n_rows, k_max), 0, n_rows).astype(jnp.int32)
-    bell = ref.BlockEll(blocks, cols)
-    t1 = jax.random.normal(k1, (bell.n, f))
-    t2 = jax.random.normal(k2, (bell.n, f))
-    got = cheb_step_pallas(blocks, cols, t1, t2, alpha=2.5, interpret=True)
-    want = ref.cheb_step_ref(bell, t1, t2, 2.5)
+    mat = _random_bsr(kb, n_rows, density, block)
+    t1 = jax.random.normal(k1, (mat.n, f))
+    t2 = jax.random.normal(k2, (mat.n, f))
+    got = cheb_step_pallas(mat.blocks, mat.rows, mat.cols, t1, t2, alpha=2.5,
+                           interpret=True)
+    want = ref.cheb_step_ref(mat, t1, t2, 2.5)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -41,31 +51,28 @@ def test_cheb_step_property(n_rows, k_max, block, f, seed):
 @hypothesis.settings(max_examples=10, deadline=None)
 @hypothesis.given(
     n_rows=st.integers(2, 6),
-    k_max=st.integers(1, 3),
+    density=st.floats(0.0, 1.0),
     order=st.integers(1, 12),
     eta=st.integers(1, 3),
     seed=st.integers(0, 2**30),
 )
-def test_cheb_union_fused_property(n_rows, k_max, order, eta, seed):
+def test_cheb_union_fused_property(n_rows, density, order, eta, seed):
     """Property: the fused union-combine kernel == the jnp union oracle
-    for arbitrary Block-ELL structures, orders, and union widths."""
+    for arbitrary Block-CSR structures, orders, and union widths."""
     key = jax.random.PRNGKey(seed)
-    kb, k1, kf = jax.random.split(key, 3)
-    block = 8
-    blocks = jax.random.normal(kb, (n_rows, k_max, block, block))
-    blocks = 0.3 * blocks  # keep the recurrence numerically tame
-    cols = jax.random.randint(k1, (n_rows, k_max), 0, n_rows).astype(jnp.int32)
-    bell = ref.BlockEll(blocks, cols)
-    f = jax.random.normal(kf, (bell.n, 8))
+    kb, kf = jax.random.split(key)
+    # 0.3: keep the recurrence numerically tame.
+    mat = _random_bsr(kb, n_rows, density, 8, scale=0.3)
+    f = jax.random.normal(kf, (mat.n, 8))
     coeffs = np.asarray(
         jax.random.normal(kf, (eta, order + 1)), np.float64)
     lmax = 3.0
     got = cheb_union_pallas(
-        blocks, cols, f,
+        mat.blocks, mat.rows, mat.cols, f,
         coeffs=tuple(tuple(float(x) for x in row) for row in coeffs),
         lmax=lmax, interpret=True)
     want = chebyshev.cheb_apply(
-        lambda v: ref.bsr_matvec_ref(bell, v), f,
+        lambda v: ref.bsr_matvec_ref(mat, v), f,
         jnp.asarray(coeffs, f.dtype), lmax)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)

@@ -16,18 +16,25 @@ from repro.kernels import autotune, ops, ref
 from repro.kernels.cheb_bsr import cheb_step_pallas
 
 
-def _random_bell(key, n_rows, k_max, block, dtype=jnp.float32, sym=True):
-    """Random Block-ELL matrix with valid (sorted, in-range) columns."""
-    kb, kc = jax.random.split(key)
-    blocks = jax.random.normal(kb, (n_rows, k_max, block, block), dtype)
-    cols = np.stack([
-        np.random.RandomState(i).choice(n_rows, size=k_max, replace=False)
-        for i in range(n_rows)
-    ]).astype(np.int32)
-    return ref.BlockEll(blocks, jnp.asarray(cols))
+def _random_bsr(key, n_rows, per_row, block, dtype=jnp.float32):
+    """Random Block-CSR matrix: ``per_row`` distinct block-columns in each
+    block-row, random tiles."""
+    mask = np.zeros((n_rows, n_rows), dtype=bool)
+    for i in range(n_rows):
+        mask[i, np.random.RandomState(i).choice(n_rows, size=per_row, replace=False)] = True
+    return _bsr_of_mask(key, mask, block, dtype)
 
 
-def _laplacian_bell(n=96, block=8, seed=0):
+def _bsr_of_mask(key, mask, block, dtype=jnp.float32):
+    """Block-CSR with random tiles wherever ``mask`` holds, and the zero
+    tiles ``ref.stored_tiles`` adds to keep empty rows."""
+    rows, cols = np.nonzero(ref.stored_tiles(mask))
+    blocks = jax.random.normal(key, (len(rows), block, block), dtype)
+    blocks = blocks * jnp.asarray(mask[rows, cols], dtype)[:, None, None]
+    return ref.BlockCsr(blocks, jnp.asarray(rows, jnp.int32), jnp.asarray(cols, jnp.int32))
+
+
+def _laplacian_bsr(n=96, block=8, seed=0):
     g = graph.connected_sensor_graph(
         jax.random.PRNGKey(seed), n=n, sigma=0.17, kappa=0.18)
     lap = np.asarray(g.laplacian())
@@ -38,8 +45,8 @@ def _laplacian_bell(n=96, block=8, seed=0):
 
 
 def test_bsr_from_dense_roundtrip():
-    bell, lap, _ = _laplacian_bell()
-    dense = np.asarray(ref.bsr_to_dense(bell))
+    mat, lap, _ = _laplacian_bsr()
+    dense = np.asarray(ref.bsr_to_dense(mat))
     n = lap.shape[0]
     np.testing.assert_allclose(dense[:n, :n], lap, atol=1e-6)
     assert np.all(dense[n:, :] == 0) and np.all(dense[:, n:] == 0)
@@ -49,20 +56,55 @@ def test_bsr_from_dense_roundtrip():
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_cheb_step_matches_ref(block, f, ftile, dtype):
     key = jax.random.PRNGKey(0)
-    bell = _random_bell(key, n_rows=6, k_max=3, block=block, dtype=dtype)
+    mat = _random_bsr(key, n_rows=6, per_row=3, block=block, dtype=dtype)
     k1, k2 = jax.random.split(key)
-    t1 = jax.random.normal(k1, (bell.n, f), dtype)
-    t2 = jax.random.normal(k2, (bell.n, f), dtype)
+    t1 = jax.random.normal(k1, (mat.n, f), dtype)
+    t2 = jax.random.normal(k2, (mat.n, f), dtype)
     alpha = 3.7
     for first in (False, True):
         got = cheb_step_pallas(
-            bell.blocks, bell.cols, t1, t2,
+            mat.blocks, mat.rows, mat.cols, t1, t2,
             alpha=alpha, first=first, f_tile=ftile, interpret=True)
-        want = ref.cheb_step_ref(bell, t1, t2, alpha, first=first)
+        want = ref.cheb_step_ref(mat, t1, t2, alpha, first=first)
         tol = 1e-5 if dtype == jnp.float32 else 5e-2
         np.testing.assert_allclose(
             np.asarray(got, np.float64), np.asarray(want, np.float64),
             rtol=tol, atol=tol)
+
+
+def _masks() -> dict[str, np.ndarray]:
+    """Tile masks of 7 block-rows: one empty row, one hub row holding a
+    tile in every column, and rows of a single tile."""
+    rng = np.random.default_rng(11)
+    mixed = rng.random((7, 7)) < 0.35
+    mixed[3] = False
+    hub = np.eye(7, dtype=bool)
+    hub[0] = True
+    hub[:, 0] = True
+    single = np.zeros((7, 7), dtype=bool)
+    single[np.arange(7), (3 * np.arange(7) + 2) % 7] = True
+    return {"empty_row": mixed, "hub_row": hub, "single_tile_rows": single}
+
+
+@pytest.mark.parametrize("name", sorted(_masks()))
+def test_stored_tile_step_matches_dense(name):
+    """One grid step per stored tile: the step kernel equals the eq. 9
+    step with the densified matrix, in float64; a row with no nonzero
+    tile still gets its ``-2 t1 - t2`` (its zero tile is stored)."""
+    mask = _masks()[name]
+    mat = _bsr_of_mask(jax.random.PRNGKey(5), mask, 8)
+    assert mat.n_tiles == int(ref.stored_tiles(mask).sum())
+    assert mat.n_tiles == mask.sum() + (~mask.any(axis=1)).sum()
+    lap = np.asarray(ref.bsr_to_dense(mat), np.float64)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(6))
+    t1 = jax.random.normal(k1, (mat.n, 16))
+    t2 = jax.random.normal(k2, (mat.n, 16))
+    a1, a2 = np.asarray(t1, np.float64), np.asarray(t2, np.float64)
+    for first in (False, True):
+        got = cheb_step_pallas(mat.blocks, mat.rows, mat.cols, t1, t2,
+                               alpha=2.5, first=first, interpret=True)
+        want = (lap @ a1 / 2.5 - a1) if first else (lap @ a1 / 1.25 - 2.0 * a1 - a2)
+        np.testing.assert_allclose(np.asarray(got, np.float64), want, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("f", [8, 128, 256])
@@ -73,32 +115,32 @@ def test_select_tiling_returns_lowerable_f_tile(key, f):
     block, dtype = key
     for n in (256, 4096, 16384):
         for krylov in (jnp.float32, jnp.bfloat16):
-            t = autotune.select_tiling(n, f, 5, n // block, 8, block, dtype,
+            t = autotune.select_tiling(n, f, 5, 8 * (n // block), block, dtype,
                                        krylov_dtype=krylov)
             assert f % t.f_tile == 0, (n, krylov, t)
             assert t.f_tile % 128 == 0 or t.f_tile == f, (n, krylov, t)
 
 
 def test_full_apply_matches_dense_oracle():
-    bell, lap, lmax = _laplacian_bell(n=96, block=8)
+    mat, lap, lmax = _laplacian_bsr(n=96, block=8)
     bank = [multipliers.heat(0.6), multipliers.tikhonov(1.0, 1)]
     coeffs = chebyshev.cheb_coefficients(bank, order=15, lmax=lmax)
-    f = jax.random.normal(jax.random.PRNGKey(3), (bell.n, 8))
+    f = jax.random.normal(jax.random.PRNGKey(3), (mat.n, 8))
     got = ops.cheb_apply_bsr(
-        bell.blocks, bell.cols, f, coeffs, lmax, interpret=True)
-    want = ref.cheb_apply_bsr_ref(bell, f, coeffs, lmax)
+        mat.blocks, mat.rows, mat.cols, f, coeffs, lmax, interpret=True)
+    want = ref.cheb_apply_bsr_ref(mat, f, coeffs, lmax)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
 
 def test_full_apply_agrees_with_core_dense_path():
     # Kernel path == core dense path on the unpadded region.
-    bell, lap, lmax = _laplacian_bell(n=64, block=8)
+    mat, lap, lmax = _laplacian_bsr(n=64, block=8)
     coeffs = chebyshev.cheb_coefficients([multipliers.heat(1.0)], 12, lmax)
     n = lap.shape[0]
-    f = jax.random.normal(jax.random.PRNGKey(4), (bell.n, 4))
+    f = jax.random.normal(jax.random.PRNGKey(4), (mat.n, 4))
     f = f.at[n:].set(0.0)
-    got = ops.cheb_apply_bsr(bell.blocks, bell.cols, f, coeffs, lmax,
+    got = ops.cheb_apply_bsr(mat.blocks, mat.rows, mat.cols, f, coeffs, lmax,
                              interpret=True)
     dense = chebyshev.cheb_apply_dense(jnp.asarray(lap), f[:n], coeffs, lmax)
     np.testing.assert_allclose(np.asarray(got)[:, :n], np.asarray(dense),
@@ -107,17 +149,15 @@ def test_full_apply_agrees_with_core_dense_path():
 
 def test_kernel_linearity_property():
     """Phi~ is linear: kernel(a f + b g) == a kernel(f) + b kernel(g)."""
-    bell, _, lmax = _laplacian_bell(n=64, block=8)
+    mat, _, lmax = _laplacian_bsr(n=64, block=8)
     coeffs = chebyshev.cheb_coefficients([multipliers.heat(0.5)], 10, lmax)
     kf, kg = jax.random.split(jax.random.PRNGKey(9))
-    f = jax.random.normal(kf, (bell.n, 4))
-    g = jax.random.normal(kg, (bell.n, 4))
-    lhs = ops.cheb_apply_bsr(bell.blocks, bell.cols, 2.0 * f - 3.0 * g,
-                             coeffs, lmax, interpret=True)
-    rhs = (2.0 * ops.cheb_apply_bsr(bell.blocks, bell.cols, f, coeffs, lmax,
-                                    interpret=True)
-           - 3.0 * ops.cheb_apply_bsr(bell.blocks, bell.cols, g, coeffs,
-                                      lmax, interpret=True))
+    f = jax.random.normal(kf, (mat.n, 4))
+    g = jax.random.normal(kg, (mat.n, 4))
+    ops_ = (mat.blocks, mat.rows, mat.cols)
+    lhs = ops.cheb_apply_bsr(*ops_, 2.0 * f - 3.0 * g, coeffs, lmax, interpret=True)
+    rhs = (2.0 * ops.cheb_apply_bsr(*ops_, f, coeffs, lmax, interpret=True)
+           - 3.0 * ops.cheb_apply_bsr(*ops_, g, coeffs, lmax, interpret=True))
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs),
                                rtol=2e-4, atol=2e-4)
 
@@ -127,14 +167,13 @@ def test_cheb_step_tpu_production_shapes():
     """TPU-aligned BlockSpec shapes (128x128 tiles, F=256) in interpret
     mode — validates the exact tiling the production kernel would run."""
     key = jax.random.PRNGKey(42)
-    bell = _random_bell(key, n_rows=4, k_max=3, block=128,
-                        dtype=jnp.bfloat16)
+    mat = _random_bsr(key, n_rows=4, per_row=3, block=128, dtype=jnp.bfloat16)
     k1, k2 = jax.random.split(key)
-    t1 = jax.random.normal(k1, (bell.n, 256), jnp.bfloat16)
-    t2 = jax.random.normal(k2, (bell.n, 256), jnp.bfloat16)
-    got = cheb_step_pallas(bell.blocks, bell.cols, t1, t2,
+    t1 = jax.random.normal(k1, (mat.n, 256), jnp.bfloat16)
+    t2 = jax.random.normal(k2, (mat.n, 256), jnp.bfloat16)
+    got = cheb_step_pallas(mat.blocks, mat.rows, mat.cols, t1, t2,
                            alpha=4.0, f_tile=128, interpret=True)
-    want = ref.cheb_step_ref(bell, t1, t2, 4.0)
+    want = ref.cheb_step_ref(mat, t1, t2, 4.0)
     g = np.asarray(got, np.float64)
     w = np.asarray(want, np.float64)
     scale = np.max(np.abs(w)) + 1e-9

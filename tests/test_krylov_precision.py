@@ -104,7 +104,7 @@ def test_gram_and_higher_order_paths_accept_krylov_dtype(setting):
 
 
 def test_bf16_halves_krylov_vmem_term():
-    args = dict(n=4096, f_tile=128, eta=3, n_rows=32, k_max=8, block=128)
+    args = dict(n=4096, f_tile=128, eta=3, n_tiles=256, block=128)
     f32 = autotune.union_vmem_bytes(*args.values())
     bf16 = autotune.union_vmem_bytes(
         *args.values(), krylov_dtype=jnp.bfloat16)
@@ -115,13 +115,12 @@ def test_bf16_halves_krylov_vmem_term():
 def test_bf16_raises_fuse_threshold():
     """A budget chosen between the bf16 and f32 working sets: f32 falls
     back to stepwise, bf16 fuses at the same shape."""
-    shape = dict(n=4096, f=128, eta=3, n_rows=32, k_max=8, block=128)
+    shape = dict(n=4096, f=128, eta=3, n_tiles=256, block=128)
     f32_bytes = autotune.union_vmem_bytes(
-        shape["n"], 128, shape["eta"], shape["n_rows"], shape["k_max"],
-        shape["block"])
+        shape["n"], 128, shape["eta"], shape["n_tiles"], shape["block"])
     bf16_bytes = autotune.union_vmem_bytes(
-        shape["n"], 128, shape["eta"], shape["n_rows"], shape["k_max"],
-        shape["block"], krylov_dtype=jnp.bfloat16)
+        shape["n"], 128, shape["eta"], shape["n_tiles"], shape["block"],
+        krylov_dtype=jnp.bfloat16)
     budget = (f32_bytes + bf16_bytes) // 2
     t_f32 = autotune.select_tiling(*shape.values(), vmem_budget=budget)
     t_bf16 = autotune.select_tiling(

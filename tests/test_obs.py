@@ -198,6 +198,22 @@ def test_engine_cache_misses_equal_panel_program_compiles(small_filter):
     assert sum(panel.values()) == eng.recompiles
 
 
+def test_layout_record_is_always_on(small_filter):
+    """With recording off, preparing ``bsr`` leaves its layout in the
+    snapshot: block, block-rows, stored tiles and their bytes, beside the
+    Laplacian's vertex count and nonzeros; ``diff`` keeps the later one."""
+    filt, _ = small_filter
+    assert not obs.enabled()
+    before = obs.snapshot()
+    mat = filt.prepare_backend("bsr", block_size=16).mat
+    snap = obs.snapshot()
+    lap = np.asarray(filt.graph.laplacian())
+    assert snap["layout"] == {
+        "backend": "bsr", "n": 96, "nnz": int(np.count_nonzero(lap)), "block": 16,
+        "block_rows": 6, "stored_tiles": mat.n_tiles, "tile_bytes": mat.n_tiles * 16 * 16 * 4}
+    assert obs.diff(snap, before)["layout"] == snap["layout"]
+
+
 def test_kernel_trace_counts_read_the_counter():
     from repro.dynamic import delta
 

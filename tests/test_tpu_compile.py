@@ -4,8 +4,9 @@ No chip is attached: ``get_topology_desc`` describes one, and the TPU
 compiler raises here what it would raise on the chip (SMEM or VMEM
 exhaustion, block shapes the lowering refuses). Shapes are those of the
 16,384-sensor smoke field (``chip_smoke.py``): F=128 signals, an eta=5
-bank at order M=20, the Block-ELL tiling at every block size the ``bsr``
-backend can choose.
+bank at order M=20, the Block-CSR tiling at every block size the ``bsr``
+backend can choose; and the Graph500 graph at scale 14 in its degree
+order, where one block-row holds far more tiles than the others.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every pytest worker imports this
@@ -27,9 +28,12 @@ F = 128
 ETA = 5
 ORDER = 20
 LMAX = 30.0
-# k_max of the smoke field's Block-ELL tiling (seed 0) at each block size
-# ``autotune.choose_block_size`` weighs.
-K_MAX = {8: 13, 16: 9, 32: 10, 64: 9, 128: 10, 256: 9}
+# Stored tiles of the smoke field's Block-CSR tiling (seed 0) at each block
+# size ``autotune.choose_block_size`` weighs.
+N_TILES = {8: 15_940, 16: 7_490, 32: 3_530, 64: 1_782, 128: 856, 256: 422}
+# The same for the Graph500 graph at scale 14 (seed 0) at the blocks its
+# chooser weighs most closely.
+GRAPH500_TILES = {64: 17_456, 128: 6_346, 256: 2_018}
 
 
 @pytest.fixture(scope="module")
@@ -57,10 +61,10 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _bell_specs(sharding, n, block, k_max):
-    n_rows = n // block
-    return (_spec(sharding, (n_rows, k_max, block, block)),
-            _spec(sharding, (n_rows, k_max), jnp.int32))
+def _bsr_specs(sharding, block, n_tiles):
+    return (_spec(sharding, (n_tiles, block, block)),
+            _spec(sharding, (n_tiles,), jnp.int32),
+            _spec(sharding, (n_tiles,), jnp.int32))
 
 
 def _compile(fn, *specs) -> str:
@@ -74,17 +78,19 @@ def _coeffs(eta, order):
                  for j in range(eta))
 
 
-@pytest.mark.parametrize("block", sorted(K_MAX))
-def test_step_kernel_compiles(one_chip, block):
+@pytest.mark.parametrize("block,n_tiles", [
+    *(pytest.param(b, t, id=str(b)) for b, t in sorted(N_TILES.items())),
+    *(pytest.param(b, t, id=f"graph500-{b}") for b, t in sorted(GRAPH500_TILES.items()))])
+def test_step_kernel_compiles(one_chip, block, n_tiles):
     """The stepwise kernel at N=16384 at every block the chooser can
-    return; block 8 once ran out of SMEM (its 2-D prefetched column table
-    padded to 128 words per block-row)."""
-    assert set(K_MAX) == set(autotune.BLOCK_CANDIDATES)
-    blocks, cols = _bell_specs(one_chip, N, block, K_MAX[block])
+    return; its two prefetched index tables take 8 bytes a stored tile of
+    SMEM (a 2-D table once padded to 128 words per block-row and ran out
+    at block 8)."""
+    assert set(N_TILES) == set(autotune.BLOCK_CANDIDATES)
     t = _spec(one_chip, (N, F))
     _compile(
-        lambda b, c, t1, t2: cheb_step_pallas(b, c, t1, t2, alpha=LMAX / 2),
-        blocks, cols, t, t)
+        lambda b, r, c, t1, t2: cheb_step_pallas(b, r, c, t1, t2, alpha=LMAX / 2),
+        *_bsr_specs(one_chip, block, n_tiles), t, t)
 
 
 @pytest.mark.parametrize("block", autotune.BLOCK_CANDIDATES)
@@ -93,30 +99,28 @@ def test_bf16_tiling_answer_compiles(one_chip, block):
     mode compiles at every block the chooser can return. It once fused at
     f_tile=8, a block the lowering refuses; the answer now is the stepwise
     chain at a 128-lane tile."""
-    k_max = K_MAX[block]
+    n_tiles = N_TILES[block]
     tiling = autotune.select_tiling(
-        N, F, ETA, N // block, k_max, block, krylov_dtype=jnp.bfloat16)
+        N, F, ETA, n_tiles, block, krylov_dtype=jnp.bfloat16)
     assert tiling.f_tile % 128 == 0 or tiling.f_tile == F
     assert not tiling.fuse
-    blocks, cols = _bell_specs(one_chip, N, block, k_max)
     _compile(
-        lambda b, c, x, co: ops.cheb_apply_bsr(
-            b, c, x, co, LMAX, f_tile=tiling.f_tile,
+        lambda b, r, c, x, co: ops.cheb_apply_bsr(
+            b, r, c, x, co, LMAX, f_tile=tiling.f_tile,
             krylov_dtype="bfloat16"),
-        blocks, cols, _spec(one_chip, (N, F)),
+        *_bsr_specs(one_chip, block, n_tiles), _spec(one_chip, (N, F)),
         _spec(one_chip, (ETA, ORDER + 1)))
 
 
 def test_fused_kernel_compiles_where_tiling_fuses(one_chip):
     """A shape ``select_tiling`` fuses in f32, close under its VMEM budget:
     the compiler must accept what the budget model admits."""
-    n, block, k_max = 1024, 128, 2
-    tiling = autotune.select_tiling(n, F, ETA, n // block, k_max, block)
+    n, block, n_tiles = 1024, 128, 16
+    tiling = autotune.select_tiling(n, F, ETA, n_tiles, block)
     assert tiling.fuse
     assert tiling.vmem_bytes > autotune.VMEM_BUDGET_BYTES // 2
-    blocks, cols = _bell_specs(one_chip, n, block, k_max)
     _compile(
-        lambda b, c, x: cheb_union_pallas(
-            b, c, x, coeffs=_coeffs(ETA, ORDER), lmax=LMAX,
+        lambda b, r, c, x: cheb_union_pallas(
+            b, r, c, x, coeffs=_coeffs(ETA, ORDER), lmax=LMAX,
             f_tile=tiling.f_tile),
-        blocks, cols, _spec(one_chip, (n, F)))
+        *_bsr_specs(one_chip, block, n_tiles), _spec(one_chip, (n, F)))
