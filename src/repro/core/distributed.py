@@ -616,15 +616,131 @@ def allgather_matvec(x_local, l_rows, axis_name: str):
     return jnp.tensordot(l_rows, x_full, axes=1)
 
 
+@partial(jax.jit, static_argnames="scope")
+def _take_vertices(x, idx, *, scope: str):
+    """``x[..., idx, :]`` on ``x``'s device; an index past the end reads
+    zero (the plan's pad slots)."""
+    with jax.named_scope(scope):
+        return jnp.take(x, idx, axis=-2, mode="fill", fill_value=0)
+
+
+def _copy_on(y, device) -> jax.Array:
+    """``device``'s own copy of ``y``: the buffer it already holds when
+    ``y`` is whole there, else one explicit ``device_put``."""
+    if isinstance(y, jax.Array) and y.sharding.is_fully_replicated:
+        for shard in y.addressable_shards:
+            if shard.device == device:
+                return shard.data
+    return jax.device_put(y, device)
+
+
+def _waited(fn):
+    """``fn``, returning once its result is ready.
+
+    XLA:CPU runs each device's share of a collective program on one shared
+    thread pool, and a program dispatched while an earlier one still runs
+    can take the threads that the earlier one's rendezvous waits for: the
+    process then aborts. So on the CPU the mesh programs run one at a
+    time; on an accelerator each device runs its programs in order, and
+    the result stays asynchronous.
+    """
+
+    def run(*args):
+        out = fn(*args)
+        out.block_until_ready()
+        return out
+
+    return run
+
+
+class _MeshLayout:
+    """What both context classes share: the plans' operands placed on the
+    mesh once, and the permutation between the caller's vertex order and
+    the partition order run on the device.
+
+    Scatter takes the signal with the padded order on the signal's own
+    device (pad slots read zero) and places it on ``P(axis)`` by one
+    explicit ``device_put``. Every program all-gathers its result over the
+    mesh before it returns, so each device holds it whole; gather takes
+    the caller's device's copy and inverts the order there. Neither
+    direction stages the signal in host memory.
+    """
+
+    def _place(self, plans) -> tuple[PartitionPlan, ...]:
+        """``plans`` with their tables on ``P(axis)``; the layout's indices
+        on the default device."""
+        shard = NamedSharding(self.mesh, P(self.axis))
+        placed = tuple(
+            dataclasses.replace(
+                p, l_own=jax.device_put(p.l_own, shard),
+                l_halo=jax.device_put(p.l_halo, shard),
+                send_idx=jax.device_put(p.send_idx, shard))
+            for p in plans)
+        plan = placed[0]
+        fwd = np.full(plan.n_local * plan.n_parts, plan.n, np.int32)
+        fwd[: plan.n] = plan.order  # pad slots index past the end
+        inv = np.empty(plan.n, np.int32)
+        inv[plan.order] = np.arange(plan.n)
+        self._dev.update(fwd=jax.device_put(fwd), inv=jax.device_put(inv))
+        return placed
+
+    def replicate(self, x, dtype) -> jax.Array:
+        """``x`` in ``dtype``, whole on every device of the mesh: as given
+        when it is already, else through one explicit ``device_put``."""
+        x = x.astype(dtype) if isinstance(x, jax.Array) else np.asarray(x, dtype)
+        return jax.device_put(x, NamedSharding(self.mesh, P()))
+
+    def constant(self, name: str, value, dtype) -> jax.Array:
+        """:meth:`replicate` of ``value``, placed on the first call for
+        ``(name, dtype)`` and held for every later one."""
+        key = (name, np.dtype(dtype))
+        if key not in self._dev:
+            self._dev[key] = self.replicate(value, dtype)
+        return self._dev[key]
+
+    def scatter_signal(self, f) -> jax.Array:
+        """Permute+pad an (N,) or (..., N, F) signal and shard it over the
+        devices: (..., P*n_local, F) sharded along the vertex axis."""
+        f = jnp.asarray(f)
+        if f.ndim == 1:
+            f = jnp.expand_dims(f, -1)
+        fp = _take_vertices(f, self._dev["fwd"], scope="repro.halo.forward_perm")
+        spec = P(*([None] * (f.ndim - 2)), self.axis)
+        return jax.device_put(fp, NamedSharding(self.mesh, spec))
+
+    def gather_signal(self, y, device=None) -> jax.Array:
+        """Invert scatter: (..., P*n_local, F) -> (..., N, F) in input
+        order, on ``device`` (default: the default device). A result of
+        this context's programs is already whole on every device, so no
+        copy leaves the device that holds it."""
+        y = _copy_on(y, device or jax.devices()[0])
+        return _take_vertices(y, self._dev["inv"], scope="repro.halo.inverse_perm")
+
+    def _program(self, key, local_fn, in_specs, out_specs):
+        """``jit(shard_map(local_fn))``, cached under ``key``; its
+        ``out_specs`` result is all-gathered over the mesh on the way out."""
+        fn = self._programs.get(key)
+        if fn is None:
+            fn = jax.jit(
+                shard_map(local_fn, mesh=self.mesh,
+                          in_specs=in_specs, out_specs=out_specs),
+                out_shardings=NamedSharding(self.mesh, P()))
+            if self.mesh.devices.flat[0].platform == "cpu":
+                fn = _waited(fn)
+            self._programs[key] = fn
+        return fn
+
+
 @dataclasses.dataclass(frozen=True)
-class DistributedGraphContext:
+class DistributedGraphContext(_MeshLayout):
     """Binds a PartitionPlan to a mesh axis and exposes distributed ops.
 
     Compiled shard_map programs are cached per (backend, schedule) in
     ``_programs`` — coefficients and ``lmax`` enter as runtime arguments,
     so one traced program serves every filter order/eta combination
     (apply and gram reuse the same cache entry) instead of re-tracing the
-    collective program on every call.
+    collective program on every call. ``plan`` holds its tables on the
+    mesh from construction on.
     """
 
     plan: PartitionPlan
@@ -632,36 +748,12 @@ class DistributedGraphContext:
     axis: str
     _programs: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    _dev: dict = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
 
-    def _specs(self):
-        return P(self.axis)
-
-    def _program(self, key, local_fn, in_specs, out_specs):
-        fn = self._programs.get(key)
-        if fn is None:
-            fn = jax.jit(shard_map(
-                local_fn, mesh=self.mesh,
-                in_specs=in_specs, out_specs=out_specs))
-            self._programs[key] = fn
-        return fn
-
-    def scatter_signal(self, f) -> jax.Array:
-        """Permute+pad a global (N, F) or (N,) signal and shard over devices.
-
-        Returns (P*n_local, F) array sharded along the vertex axis.
-        """
-        f = jnp.atleast_2d(jnp.asarray(f).T).T  # (N,) -> (N, 1)
-        pad = self.plan.n_local * self.plan.n_parts - self.plan.n
-        fp = jnp.concatenate([f[self.plan.order], jnp.zeros((pad,) + f.shape[1:], f.dtype)])
-        return jax.device_put(
-            fp, NamedSharding(self.mesh, P(self.axis)))
-
-    def gather_signal(self, y) -> np.ndarray:
-        """Invert scatter: (..., P*n_local, F) -> (..., N, F) in input order."""
-        y = np.asarray(y)
-        inv = np.empty_like(self.plan.order)
-        inv[self.plan.order] = np.arange(self.plan.n)
-        return y[..., inv, :]
+    def __post_init__(self):
+        if self.mesh is not None:
+            object.__setattr__(self, "plan", self._place((self.plan,))[0])
 
     def cheb_apply(
         self, f_sharded, coeffs, lmax, backend: str = "halo",
@@ -678,13 +770,14 @@ class DistributedGraphContext:
           (:func:`halo_cheb_apply_overlapped`, default) or the serial
           exchange->matvec reference (``overlap=False``); identical
           results up to f32 rounding, same message count.
-        Returns (eta, P*n_local, F) sharded along the vertex axis.
+        coeffs, lmax: placed by :meth:`replicate` unless they already are.
+        Returns (eta, P*n_local, F), whole on every device of the mesh.
         """
         from repro.core import chebyshev  # local import to avoid cycle
 
         plan = self.plan
-        coeffs = jnp.asarray(coeffs, f_sharded.dtype)
-        lmax = jnp.asarray(lmax, f_sharded.dtype)
+        coeffs = self.replicate(coeffs, f_sharded.dtype)
+        lmax = self.replicate(lmax, f_sharded.dtype)
         axis = self.axis
 
         if backend == "halo":
@@ -712,10 +805,10 @@ class DistributedGraphContext:
                       plan.l_own, plan.l_halo, plan.send_idx)
 
         elif backend == "allgather":
-            l_rows = self._programs.get("l_rows")
+            l_rows = self._dev.get("l_rows")
             if l_rows is None:
-                l_rows = plan_row_slabs(plan)
-                self._programs["l_rows"] = l_rows
+                l_rows = self._dev["l_rows"] = jax.device_put(
+                    plan_row_slabs(plan), NamedSharding(self.mesh, P(axis)))
 
             def local_fn(f_loc, coeffs, lmax, l_rows_loc):
                 mv = lambda v: allgather_matvec(v, l_rows_loc[0], axis)
@@ -732,12 +825,12 @@ class DistributedGraphContext:
         """Distributed ``Phi~* a`` (paper Sec. IV-B: length-eta messages).
 
         a_sharded: (eta, P*n_local, F) sharded along the vertex axis.
-        Returns (P*n_local, F)."""
+        Returns (P*n_local, F), whole on every device of the mesh."""
         from repro.core import chebyshev
 
         plan = self.plan
-        coeffs = jnp.asarray(coeffs, a_sharded.dtype)
-        lmax = jnp.asarray(lmax, a_sharded.dtype)
+        coeffs = self.replicate(coeffs, a_sharded.dtype)
+        lmax = self.replicate(lmax, a_sharded.dtype)
         axis = self.axis
 
         def local_fn(a_loc, coeffs, lmax, l_own, l_halo, send_idx):
@@ -758,7 +851,7 @@ class DistributedGraphContext:
         """Distributed ``Phi~* Phi~ f`` as one degree-2M filter
         (Sec. IV-C, 4M|E| messages)."""
         out = self.cheb_apply(
-            f_sharded, jnp.asarray(op.gram_coeffs)[None, :], op.lmax,
+            f_sharded, np.asarray(op.gram_coeffs)[None, :], op.lmax,
             backend=backend, overlap=overlap)
         return out[0]
 
@@ -808,7 +901,7 @@ class DistributedGraphContext:
 
 
 @dataclasses.dataclass(frozen=True)
-class MultiShiftGraphContext:
+class MultiShiftGraphContext(_MeshLayout):
     """Distributed context for a multi-shift joint filter (DESIGN.md
     Sec. 11): R per-shift :class:`PartitionPlan` s over ONE shared vertex
     layout (built by :func:`build_shift_partition_plans`), bound to a mesh
@@ -816,7 +909,7 @@ class MultiShiftGraphContext:
 
     The joint recurrence runs inside a single ``shard_map`` program whose
     per-shift matvec closures each do their own halo exchange — so one
-    scatter/gather round-trips the signal, while every matvec in shift r
+    scatter and one gather move the signal, while every matvec in shift r
     moves exactly ``plans[r].halo_words`` words. Words per apply is the
     per-shift sum ``sum_r count_r * halo_words_r`` with
     ``count_r = M_r * prod_{s<r}(M_s + 1)``
@@ -829,6 +922,12 @@ class MultiShiftGraphContext:
     lmaxes: tuple[float, ...]
     _programs: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    _dev: dict = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
+
+    def __post_init__(self):
+        if self.mesh is not None:
+            object.__setattr__(self, "plans", self._place(self.plans))
 
     @property
     def plan(self) -> PartitionPlan:
@@ -837,20 +936,6 @@ class MultiShiftGraphContext:
         that only need the layout read them here."""
         return self.plans[0]
 
-    def scatter_signal(self, f) -> jax.Array:
-        f = jnp.atleast_2d(jnp.asarray(f).T).T  # (N,) -> (N, 1)
-        plan = self.plan
-        pad = plan.n_local * plan.n_parts - plan.n
-        fp = jnp.concatenate(
-            [f[plan.order], jnp.zeros((pad,) + f.shape[1:], f.dtype)])
-        return jax.device_put(fp, NamedSharding(self.mesh, P(self.axis)))
-
-    def gather_signal(self, y) -> np.ndarray:
-        y = np.asarray(y)
-        inv = np.empty_like(self.plan.order)
-        inv[self.plan.order] = np.arange(self.plan.n)
-        return y[..., inv, :]
-
     def _tables(self):
         out = []
         for plan in self.plans:
@@ -858,26 +943,20 @@ class MultiShiftGraphContext:
         return tuple(out)
 
     def _joint_program(self, key, local_fn, lead_specs, out_specs):
-        fn = self._programs.get(key)
-        if fn is None:
-            table_specs = (P(self.axis),) * (3 * len(self.plans))
-            fn = jax.jit(shard_map(
-                local_fn, mesh=self.mesh,
-                in_specs=lead_specs + table_specs, out_specs=out_specs))
-            self._programs[key] = fn
-        return fn
+        table_specs = (P(self.axis),) * (3 * len(self.plans))
+        return self._program(key, local_fn, lead_specs + table_specs, out_specs)
 
     def cheb_apply_joint(self, f_sharded, coeffs):
         """Distributed joint ``Phi~ f``: per-shift halo exchange inside one
         shard_map program. f_sharded: (P*n_local, F) sharded along the
         vertex axis; coeffs: (eta, M_1+1, ..., M_R+1). Returns
-        (eta, P*n_local, F)."""
+        (eta, P*n_local, F), whole on every device of the mesh."""
         from repro.core import chebyshev  # local import to avoid cycle
 
         r = len(self.plans)
         lmaxes = self.lmaxes
         axis = self.axis
-        coeffs = jnp.asarray(coeffs, f_sharded.dtype)
+        coeffs = self.replicate(coeffs, f_sharded.dtype)
 
         def local_fn(f_loc, coeffs, *tables):
             mvs = [
@@ -900,13 +979,14 @@ class MultiShiftGraphContext:
 
     def cheb_adjoint_joint(self, a_sharded, coeffs):
         """Distributed joint ``Phi~* a`` for a_sharded shaped
-        (eta, P*n_local, F) sharded along the vertex axis."""
+        (eta, P*n_local, F) sharded along the vertex axis; returns
+        (P*n_local, F), whole on every device of the mesh."""
         from repro.core import chebyshev
 
         r = len(self.plans)
         lmaxes = self.lmaxes
         axis = self.axis
-        coeffs = jnp.asarray(coeffs, a_sharded.dtype)
+        coeffs = self.replicate(coeffs, a_sharded.dtype)
 
         def local_fn(a_loc, coeffs, *tables):
             mvs = [

@@ -472,9 +472,10 @@ class GraphFilter:
         capability — the whole apply wrapped in one ``jax.jit``, so a
         serving engine can key compiled programs by panel bucket and
         count recompiles exactly (one trace per program, on its first
-        call). Non-traceable backends (halo/grid stage host transfers)
-        return a plain callable; their compilation reuse lives in their
-        own prepared state.
+        call). Non-traceable backends (halo/grid place arrays on their
+        mesh with explicit ``device_put`` calls outside any trace) return
+        a plain callable; their compilation reuse lives in their own
+        prepared state.
 
         ``donate=True`` donates the panel input buffer to the program
         (``launch.donation`` discipline): the serving engine packs a fresh

@@ -78,6 +78,13 @@ def _coeffs_or(filt, coeffs) -> np.ndarray:
     )
 
 
+def _home_device(x: jax.Array):
+    """Where a distributed backend returns its result: the device ``x``
+    lives on, or the default device when ``x`` spans several."""
+    devices = x.devices()
+    return next(iter(devices)) if len(devices) == 1 else jax.devices()[0]
+
+
 def _default_mesh(axis: str, n_parts: int | None) -> Mesh:
     n = n_parts or len(jax.devices())
     return compat.make_mesh((n,), (axis,))
@@ -429,7 +436,8 @@ class _ShardedBackendBase:
 
     name = "halo"
     state_key = "partition_plan"
-    # scatter_signal/gather_signal round-trip through host numpy, so these
+    # apply/adjoint move arrays between device sets (the caller's device
+    # and the mesh) with explicit device_puts outside any trace, so these
     # backends cannot live inside a lax.scan body (traceable=False).
     capabilities = BackendCapabilities()
     prepare_opts: frozenset[str] = frozenset({"mesh", "axis", "n_parts"})
@@ -465,9 +473,10 @@ class _ShardedBackendBase:
         return DistributedGraphContext(plan=plan, mesh=mesh, axis=axis)
 
     def apply(self, filt, ctx, f, *, coeffs=None, overlap: bool = True, **_):
-        c = _coeffs_or(filt, coeffs)
         f = jnp.asarray(f)
-        squeeze = f.ndim == 1
+        home = _home_device(f)
+        c = (ctx.constant("coeffs", filt.coeffs, f.dtype) if coeffs is None
+             else ctx.replicate(_coeffs_or(filt, coeffs), f.dtype))
         aid = next(self._apply_ids) if obs.enabled() else None
         with obs.span("halo.scatter", apply=aid):
             sharded = ctx.scatter_signal(f)
@@ -478,37 +487,24 @@ class _ShardedBackendBase:
                 # overlapped schedule remains single-shift only).
                 out = ctx.cheb_apply_joint(sharded, c)
             else:
-                out = ctx.cheb_apply(sharded, c, filt.lmax, backend=self.name, overlap=overlap)
-        # The host copy below blocks here anyway; waiting first splits the
-        # device's time from the host's.
-        with obs.span("halo.wait", apply=aid):
-            out.block_until_ready()
+                lmax = ctx.constant("lmax", filt.lmax, f.dtype)
+                out = ctx.cheb_apply(sharded, c, lmax, backend=self.name, overlap=overlap)
         with obs.span("halo.gather", apply=aid):
-            out = ctx.gather_signal(np.asarray(out))
-        with obs.span("halo.put", apply=aid):
-            out = jnp.asarray(out)
-        return out[:, :, 0] if squeeze else out
+            out = ctx.gather_signal(out, home)
+        return jnp.squeeze(out, -1) if f.ndim == 1 else out
 
     def adjoint(self, filt, ctx, a, **_):
         a = jnp.asarray(a)
-        squeeze = a.ndim == 2
-        a3 = a[:, :, None] if squeeze else a
-        plan = ctx.plan
-        pad = plan.n_local * plan.n_parts - plan.n
-        ap = jnp.concatenate(
-            [
-                a3[:, plan.order],
-                jnp.zeros((a3.shape[0], pad) + a3.shape[2:], a3.dtype),
-            ],
-            axis=1,
-        )
-        ap = jax.device_put(ap, NamedSharding(ctx.mesh, P(None, ctx.axis)))
+        squeeze = a.ndim == 2  # (eta, N) -> signals are 1-D
+        a3 = jnp.expand_dims(a, -1) if squeeze else a
+        c = ctx.constant("coeffs", filt.coeffs, a.dtype)
+        ap = ctx.scatter_signal(a3)
         if isinstance(ctx, MultiShiftGraphContext):
-            out = ctx.cheb_adjoint_joint(ap, filt.coeffs)
+            out = ctx.cheb_adjoint_joint(ap, c)
         else:
-            out = ctx.cheb_adjoint(ap, filt.coeffs, filt.lmax)
-        out = jnp.asarray(ctx.gather_signal(np.asarray(out)))
-        return out[:, 0] if squeeze else out
+            out = ctx.cheb_adjoint(ap, c, ctx.constant("lmax", filt.lmax, a.dtype))
+        out = ctx.gather_signal(out, _home_device(a))
+        return jnp.squeeze(out, -1) if squeeze else out
 
     def messages_per_apply(self, filt, ctx, matvec_counts) -> int:
         if isinstance(ctx, MultiShiftGraphContext):

@@ -50,8 +50,9 @@ class BackendCapabilities:
         True iff ``apply``/``adjoint``/``gram`` stage pure jax ops end to
         end, so calls can live inside ``jax.lax.scan`` / ``while_loop``
         bodies (iterative solvers then compile their whole loop). Backends
-        that stage host-side transfers (scatter/gather round-trips through
-        numpy) must declare False — callers fall back to a host loop.
+        that move arrays between device sets with explicit ``device_put``
+        calls (the mesh backends' scatter and gather) must declare False —
+        callers fall back to a host loop.
     sparse_input : bool
         True iff the backend implements ``apply_sparse`` — applying the
         filter to a signal supported on a sparse vertex set by restricting

@@ -271,7 +271,7 @@ def test_halo_apply_spans_in_order_on_four_devices():
     assert {"filter.prepare", "halo.plan", "halo.context", "graph.spatial_order"} <= set(
         out["setup"])
     names = [a[0] for a in out["apply"]]
-    assert names == ["repro.halo." + k for k in ("scatter", "dispatch", "wait", "gather", "put")]
+    assert names == ["repro.halo." + k for k in ("scatter", "dispatch", "gather")]
     ids = {a[3] for a in out["apply"]}
     assert len(ids) == 1 and None not in ids  # one apply id
     ends = [a[2] for a in out["apply"]]
