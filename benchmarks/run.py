@@ -1,16 +1,15 @@
 """Benchmark harness: one function per paper table/figure + system
-benchmarks. Prints ``name,us_per_call,derived`` CSV rows and, at the end,
-writes the machine-readable perf-trajectory record ``BENCH_<tag>.json``
-(repo root, committed — see ``--tag``).
+benchmarks. Prints ``name,us_per_call,derived`` CSV rows. Timings are
+of the CPU or interpret-mode Pallas this runs on; the chip's numbers come
+from ``benchmarks/chip/`` alone.
 
   fig4_cheb_approx     paper Fig. 4  — multiplier approximation vs order M
   tab_denoising        paper Sec.V-B — noisy vs denoised MSE (0.250/0.013)
   tab_comm_scaling     paper Sec.IV  — message counts vs network size
   tab_wavelet_ista     paper Sec.V-C — SGWT lasso denoising + comm costs
   tab_gossip           gossip consensus contraction + bytes vs all-reduce
-  tab_kernel           Pallas fused step vs jnp reference (interpret mode)
-  tab_filter_backends  GraphFilter backend parity + fused union-combine
-                       kernel (pallas_call count, HBM T_k traffic, timing)
+  tab_kernel           Pallas step chain vs jnp reference (interpret mode)
+  tab_filter_backends  GraphFilter backend parity vs the dense oracle
   tab_solvers          solver layer — ISTA vs FISTA vs CG on the Sec. V-C
                        benchmark graph: iterations-to-tolerance, wall
                        time, words/iteration per backend
@@ -28,7 +27,7 @@ writes the machine-readable perf-trajectory record ``BENCH_<tag>.json``
                        dense oracle, steady-state churn-kernel retraces
   tab_roofline         summary of the dry-run roofline table (if present)
 
-Run: PYTHONPATH=src python -m benchmarks.run [--full] [--tag TAG]
+Run: PYTHONPATH=src python -m benchmarks.run [--full] [--only SUBSTR]
 """
 
 from __future__ import annotations
@@ -48,7 +47,7 @@ import numpy as np
 from repro.apps import wavelet_denoise_ista
 from repro.core import chebyshev, gossip, graph, multipliers
 from repro.core.distributed import DistributedGraphContext, build_partition_plan
-from repro.filters import GraphFilter, get_backend
+from repro.filters import GraphFilter
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.launch.compile_cache import use_compile_cache
@@ -63,35 +62,8 @@ from repro.solvers import (
 )
 from repro.stream import StreamingFilter, StreamingWiener
 
-ROWS: list[tuple[str, float, str]] = []
-RECORDS: list[dict] = []
-_TABLE = ""  # set by main() around each bench call
-
-
-def row(
-    name: str,
-    us: float,
-    derived: str,
-    *,
-    backend: str | None = None,
-    shape: str | None = None,
-    messages: int | None = None,
-) -> None:
-    """Emit one CSV row and its machine-readable record.
-
-    ``backend``/``shape``/``messages`` feed the BENCH_<tag>.json perf
-    trajectory (op, backend, shape, median ms, messages per PR).
-    """
-    ROWS.append((name, us, derived))
-    RECORDS.append({
-        "table": _TABLE,
-        "op": name,
-        "backend": backend,
-        "shape": shape,
-        "median_ms": round(us / 1e3, 6),
-        "messages": messages,
-        "derived": derived,
-    })
+def row(name: str, us: float, derived: str) -> None:
+    """Emit one CSV row."""
     print(f"{name},{us:.1f},{derived}", flush=True)
 
 
@@ -223,8 +195,7 @@ def _train_worker(full: bool) -> dict:
 def _emit_worker_rows(full: bool, prefix: str) -> None:
     for r in _train_worker(full)["rows"]:
         if r["name"].startswith(prefix):
-            row(r["name"], r["us"], r["derived"],
-                shape=r.get("shape"), messages=r.get("messages"))
+            row(r["name"], r["us"], r["derived"])
 
 
 def tab_gossip(full: bool) -> None:
@@ -278,10 +249,7 @@ def tab_kernel(full: bool) -> None:
 
 
 def tab_filter_backends(full: bool) -> None:
-    """Unified GraphFilter layer: per-backend parity vs the dense oracle,
-    and the fused union-combine kernel's structural claim — ONE pallas_call
-    per apply with zero per-order T_k HBM round-trips (the stepwise chain
-    issues M calls and materializes every T_k)."""
+    """Unified GraphFilter layer: per-backend parity vs the dense oracle."""
     g = graph.connected_sensor_graph(jax.random.PRNGKey(5), n=480,
                                      sigma=0.075, kappa=0.076)
     filt = GraphFilter.from_multipliers(
@@ -301,7 +269,7 @@ def tab_filter_backends(full: bool) -> None:
     # Overlapped vs serial halo schedule (DESIGN.md Sec. 6.4): the halo
     # row above is the overlapped default; time the serial reference and
     # pin schedule parity. halo_overlap re-emits the default's timing
-    # under its explicit name so the gate tracks the schedule by name.
+    # under its explicit name.
     out_serial = filt.apply(f, backend="halo", overlap=False)
     us_serial = _timeit(lambda: filt.apply(f, backend="halo", overlap=False))
     sched_err = float(jnp.max(jnp.abs(outs["halo"] - out_serial)))
@@ -330,42 +298,6 @@ def tab_filter_backends(full: bool) -> None:
         gf.apply(xg, backend="grid") - gf.apply(xg, backend="dense"))))
     row("tab_filter_backend_grid", 0.0, f"max_err_vs_dense={err:.1e}")
 
-    # Structural comparison of the two Pallas paths on identical operands.
-    state = get_backend("bsr").prepare(filt)
-    mat = state.mat
-    fp = jnp.zeros((state.n_pad, 8), f.dtype).at[: state.n].set(f[state.perm])
-    coeffs = filt.coeffs
-    lmax = filt.lmax
-    operands = (mat.blocks, mat.rows, mat.cols, fp)
-
-    def fused(blocks, rows, cols, x):
-        return kops.cheb_apply_bsr_fused(
-            blocks, rows, cols, x, coeffs, lmax, interpret=True)
-
-    def stepwise(blocks, rows, cols, x):
-        return kops.cheb_apply_bsr(
-            blocks, rows, cols, x, jnp.asarray(coeffs, x.dtype), lmax,
-            interpret=True)
-
-    step_out = stepwise(*operands)
-    n_calls = {}
-    for name, fn in (("fused", fused), ("stepwise", stepwise)):
-        jaxpr = jax.make_jaxpr(fn)(*operands)
-        n_calls[name] = str(jaxpr).count("pallas_call")
-        err = float(jnp.max(jnp.abs(fn(*operands) - step_out)))
-        us = _timeit(lambda: fn(*operands))
-        row(f"tab_filter_union_{name}", us,
-            f"pallas_calls={n_calls[name]};order={filt.order}"
-            f";eta={filt.eta};max_err_vs_stepwise={err:.1e}")
-    # Fused: one pallas_call for the whole apply, T_k never leaves VMEM.
-    # Stepwise: the T_1 call plus the scan-body call executed M-1 times,
-    # each storing its (N, F) T_k to HBM — M materialized tensors/apply.
-    row("tab_filter_union_summary", 0.0,
-        f"fused_pallas_calls={n_calls['fused']}"
-        f";fused_tk_hbm_tensors=0"
-        f";stepwise_exec_pallas_calls={filt.order}"
-        f";stepwise_tk_hbm_tensors={filt.order}")
-
 
 # ----------------------------------------------------------- solvers ---
 
@@ -387,7 +319,6 @@ def tab_solvers(full: bool) -> None:
     bank = multipliers.sgwt_filter_bank(lmax, n_scales=n_scales)
     filt = GraphFilter.from_multipliers(bank, order, graph=g, lmax=lmax)
     problem = LassoProblem(filt=filt, y=y, mu=mu)
-    shape = f"N={g.n_vertices},eta={filt.eta},M={order}"
 
     # Per-backend words/iteration (8 partitions; one length-1 forward +
     # one length-eta adjoint per lasso iteration). Derived from the
@@ -441,9 +372,7 @@ def tab_solvers(full: bool) -> None:
             f";target_obj={target:.4f};final_obj={obj:.4f}"
             f";budget={budget}"
             f";words_to_matched_obj_halo="
-            f"{lasso_words['halo'] * iters_to_target}",
-            backend="dense", shape=shape,
-            messages=lasso_words["halo"] * iters_to_target)
+            f"{lasso_words['halo'] * iters_to_target}")
 
     # The headline claim: FISTA reaches ISTA's 40-iteration objective in
     # <= 20 iterations (same words/iteration -> half the communication).
@@ -453,8 +382,7 @@ def tab_solvers(full: bool) -> None:
     obj_f = problem.objective(res_f.aux)
     row("tab_solvers_fista_half_iters", 0.0,
         f"ista40_obj={obj_i:.4f};fista20_obj={obj_f:.4f}"
-        f";fista_at_half_wins={int(obj_f <= obj_i)}",
-        backend="dense", shape=shape)
+        f";fista_at_half_wins={int(obj_f <= obj_i)}")
 
     # CG inverse filtering: recover f0 from the union's stacked outputs.
     obs = filt.apply(jnp.asarray(f0))
@@ -468,15 +396,13 @@ def tab_solvers(full: bool) -> None:
     row("tab_solvers_cg_inverse", us,
         f"iters_to_tol={res_cg.iterations};tol=1e-6"
         f";max_rec_err={rec_err:.1e};converged={int(res_cg.converged)}"
-        f";words_per_iter_halo={cg_words['halo']}",
-        backend="dense", shape=shape,
-        messages=cg_words["halo"] * res_cg.iterations)
+        f";words_per_iter_halo={cg_words['halo']}")
 
     # Chebyshev-preconditioned CG (DESIGN.md Sec. 11.3): the fit
     # q(L) ~= 1/(h + reg) is built once from gram_coeffs, each PCG
     # iteration pays K extra matvecs, and the acceptance bits are
     # pcg_halves (iterations <= 0.5x plain CG) and fewer_total_words —
-    # solver_precond_* rows are bench_check key rows.
+    # the solver_precond_* rows carry both.
     pre = cheb_preconditioner(gram_problem, order=32)
     conjugate_gradient(gram_problem, n_iters=budget, tol=1e-6,
                        preconditioner=pre)  # warm
@@ -496,8 +422,7 @@ def tab_solvers(full: bool) -> None:
         f";words_per_iter_halo={pcg_per_iter}"
         f";total_words_halo={total_pcg};plain_total_words={total_cg}"
         f";fewer_total_words={int(total_pcg < total_cg)}"
-        f";converged={int(res_pcg.converged)}",
-        backend="dense", shape=shape, messages=total_pcg)
+        f";converged={int(res_pcg.converged)}")
 
     # Standalone fixed-point inverse: rate known at build time, no
     # inner-product reductions (pure filter applies per sweep).
@@ -513,14 +438,11 @@ def tab_solvers(full: bool) -> None:
         f";predicted_iters="
         f"{int(np.ceil(np.log(1e-6) / np.log(res_fp.aux.rate)))}"
         f";converged={int(res_fp.converged)}"
-        f";words_per_iter_halo={fp_per_iter}",
-        backend="dense", shape=shape,
-        messages=fp_per_iter * res_fp.iterations)
+        f";words_per_iter_halo={fp_per_iter}")
 
     for be, w in lasso_words.items():
         row(f"tab_solvers_words_{be}", 0.0,
-            f"lasso_words_per_iter={w};P=8", backend=be, shape=shape,
-            messages=w)
+            f"lasso_words_per_iter={w};P=8")
 
 
 # ---------------------------------------------------------- streaming --
@@ -542,7 +464,6 @@ def tab_streaming(full: bool) -> None:
         [multipliers.tikhonov(1.0, 1)], order, graph=gg, lmax=8.0)
     f0 = (np.asarray(gg.coords[:, 0] ** 2 + gg.coords[:, 1] ** 2,
                      np.float32))
-    shape = f"N={n},M={order},P={n_parts}"
 
     lane = StreamingFilter(filt, backend="dense", n_parts=n_parts,
                            max_delta_frac=0.5)
@@ -564,8 +485,7 @@ def tab_streaming(full: bool) -> None:
 
     us_full = _timeit(lambda: filt.apply(jnp.asarray(f0), backend="dense"))
     row("tab_streaming_full_refilter", us_full,
-        f"words_per_frame={words_full}", backend="dense", shape=shape,
-        messages=words_full)
+        f"words_per_frame={words_full}")
 
     for frac, patch in ((0.02, 11), (0.05, 18), (0.10, 25), (0.25, 40)):
         y = f0.copy()
@@ -582,8 +502,7 @@ def tab_streaming(full: bool) -> None:
             f"mode={res.mode};changed={res.changed};active={res.active}"
             f";words_per_frame={res.words};words_full={words_full}"
             f";words_ratio={res.words / words_full:.3f}"
-            f";parity_vs_full={parity:.1e}",
-            backend="dense", shape=shape, messages=res.words)
+            f";parity_vs_full={parity:.1e}")
 
     # Warm-started solvers on a slowly varying scene (Sec. V-C sensor
     # benchmark): frame 1 perturbs 2% of frame 0's vertices. (a)
@@ -592,7 +511,6 @@ def tab_streaming(full: bool) -> None:
     # crosses the cold run's final objective.
     g = graph.connected_sensor_graph(jax.random.PRNGKey(11), n=500)
     ns = g.n_vertices
-    shape = f"N={ns},M={order},P={n_parts}"
     fs = np.asarray(g.coords[:, 0] ** 2 + g.coords[:, 1] ** 2 - 1.0,
                     np.float32)
     y0 = fs + 0.5 * rng.normal(size=ns).astype(np.float32)
@@ -611,8 +529,7 @@ def tab_streaming(full: bool) -> None:
     cold1 = wlane.push(y1).iterations
     row("tab_streaming_warm_wiener", us,
         f"cold_iters={cold1};warm_iters={it1};frame0_iters={it0}"
-        f";tol=1e-6;saved={cold1 - it1}",
-        backend="dense", shape=shape)
+        f";tol=1e-6;saved={cold1 - it1}")
 
     lmax = float(g.lmax_bound())
     sfilt = GraphFilter.from_multipliers(
@@ -630,8 +547,7 @@ def tab_streaming(full: bool) -> None:
     row("tab_streaming_warm_fista", 0.0,
         f"cold_iters={budget};warm_iters_to_cold_obj={warm_iters}"
         f";target_obj={target:.4f}"
-        f";warm_final_obj={p1.objective(warmr.aux):.4f}",
-        backend="dense", shape=shape)
+        f";warm_final_obj={p1.objective(warmr.aux):.4f}")
 
 
 # ------------------------------------------------------------- engine --
@@ -664,7 +580,6 @@ def tab_engine(full: bool) -> None:
     filt = GraphFilter.from_multipliers(
         [multipliers.tikhonov(1.0, 1)], order, graph=g)
     pool = loadgen.make_signal_pool(n, 64)
-    shape = f"N={n},M={order},streams={streams}"
 
     reqs = 4000 if full else 1000
     burst = loadgen.make_trace(streams, reqs / 500.0, 500.0, burst=True)
@@ -676,8 +591,7 @@ def tab_engine(full: bool) -> None:
             1e6 * rep.busy_s / max(rep.served, 1),
             f"capacity_rps={rep.capacity_rps:.0f};served={rep.served}"
             f";panels={rep.panels};recompiles={rep.recompiles}"
-            f";pad_waste={rep.pad_waste:.3f}",
-            backend="dense", shape=shape)
+            f";pad_waste={rep.pad_waste:.3f}")
 
     paced = loadgen.make_trace(streams, (reqs // 4) / 60.0, 60.0)
     p99s = {}
@@ -687,8 +601,7 @@ def tab_engine(full: bool) -> None:
         row(f"engine_{kind}_paced", 1e3 * rep.p99_ms,
             f"rate_rps=60;p50_ms={rep.p50_ms:.3f};p99_ms={rep.p99_ms:.3f}"
             f";throughput_rps={rep.throughput_rps:.0f}"
-            f";recompiles={rep.recompiles}",
-            backend="dense", shape=shape)
+            f";recompiles={rep.recompiles}")
 
     speedup = caps["async"].capacity_rps / max(caps["sync"].capacity_rps, 1e-9)
     row("engine_summary", 0.0,
@@ -696,8 +609,7 @@ def tab_engine(full: bool) -> None:
         f";async_p99_ms={p99s['async'].p99_ms:.3f}"
         f";sync_p99_ms={p99s['sync'].p99_ms:.3f}"
         f";p99_no_worse={int(p99s['async'].p99_ms <= p99s['sync'].p99_ms)}"
-        f";steady_recompiles={caps['async'].recompiles}",
-        backend="dense", shape=shape)
+        f";steady_recompiles={caps['async'].recompiles}")
 
 
 # -------------------------------------------------------------- churn --
@@ -736,7 +648,6 @@ def tab_churn(full: bool) -> None:
         birth_rate=0.2, death_rate=0.2, bump_radius=0.12)
     gen_s = time.perf_counter() - t0
     g = sc.graph0
-    shape = f"N={n_slots},M={order},P={n_parts}"
 
     # 1.5x headroom on the AM bound keeps the polynomial certified across
     # every frame (no re-expansion frames in the steady-state numbers).
@@ -804,18 +715,13 @@ def tab_churn(full: bool) -> None:
         f"frames={len(modes)};churn_frames={n_churn}"
         f";mean_churn={sc.mean_churn:.4f}"
         f";words_mean={np.mean(words_inc):.0f}"
-        f";reexpansions={lane.reexpansions};gen_s={gen_s:.2f}",
-        backend="dense", shape=shape,
-        messages=int(np.mean(words_inc)))
+        f";reexpansions={lane.reexpansions};gen_s={gen_s:.2f}")
     row("churn_full_rebuild_frame", med(lat_base) * 1e6,
         f"words_full_mean={np.mean(words_full):.0f}"
-        f";model=order*halo_words(fresh plan)",
-        backend="dense", shape=shape,
-        messages=int(np.mean(words_full)))
+        f";model=order*halo_words(fresh plan)")
     row("churn_repair_plan", med(lat_repair) * 1e6,
         f"rebuild_us={med(lat_rebuild) * 1e6:.1f}"
-        f";repair_ratio={rep_ratio:.3f}",
-        backend="dense", shape=shape)
+        f";repair_ratio={rep_ratio:.3f}")
     row("churn_summary", 0.0,
         f"latency_ratio={lat_ratio:.3f};words_ratio={words_ratio:.3f}"
         f";repair_ratio={rep_ratio:.3f};parity={parity:.1e}"
@@ -825,8 +731,7 @@ def tab_churn(full: bool) -> None:
         f";accept_repair_lt_half={int(rep_ratio < 0.5)}"
         f";accept_parity_le_1e5={int(parity <= 1e-5)}"
         f";accept_churn_le_5pct={int(sc.mean_churn <= 0.05)}"
-        f";accept_zero_retraces={int(retraces == 0)}",
-        backend="dense", shape=shape)
+        f";accept_zero_retraces={int(retraces == 0)}")
 
 
 # ----------------------------------------------------------- roofline --
@@ -856,33 +761,17 @@ BENCHES = [fig4_cheb_approx, tab_denoising, tab_comm_scaling,
 
 
 def main() -> None:
-    global _TABLE
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale trial counts (1000-trial denoising)")
     ap.add_argument("--only", default="")
-    ap.add_argument("--tag", default="local",
-                    help="suffix for the BENCH_<tag>.json perf record "
-                         "(committed records track the trajectory per PR)")
     args = ap.parse_args()
     use_compile_cache()
     print("name,us_per_call,derived")
     for bench in BENCHES:
         if args.only and args.only not in bench.__name__:
             continue
-        _TABLE = bench.__name__
         bench(args.full)
-    if args.only:
-        # A filtered run must not clobber a committed full perf record.
-        print(f"# --only set: skipping BENCH_{args.tag}.json", flush=True)
-        return
-    out = Path(__file__).resolve().parents[1] / f"BENCH_{args.tag}.json"
-    out.write_text(json.dumps(
-        {"tag": args.tag, "full": args.full,
-         "jax": jax.__version__, "platform": jax.default_backend(),
-         "rows": RECORDS},
-        indent=1) + "\n")
-    print(f"# wrote {out}", flush=True)
 
 
 if __name__ == "__main__":

@@ -145,10 +145,9 @@ def bsr_phase(filt, f, ref) -> None:
     t0 = time.perf_counter()
     state = filt.prepare_backend("bsr")
     mat = state.mat
-    tiling = autotune.select_tiling(
-        state.n_pad, f.shape[1], filt.eta, mat.n_tiles, mat.block_size, f.dtype)
+    f_tile = autotune.choose_f_tile(f.shape[1], mat.block_size, f.dtype)
     log(phase="bsr_prepare", block_size=mat.block_size,
-        n_block_rows=mat.n_block_rows, n_tiles=mat.n_tiles, tiling=tiling,
+        n_block_rows=mat.n_block_rows, n_tiles=mat.n_tiles, f_tile=f_tile,
         seconds=time.perf_counter() - t0)
 
     for kd, bound in (("float32", F32_BOUND), ("bfloat16", BF16_BOUND)):

@@ -38,7 +38,7 @@ def main() -> None:
     print(f"noisy    MSE = {jnp.mean((y - f0) ** 2):.4f}   (paper: ~0.250)")
     print(f"denoised MSE = {jnp.mean((fhat - f0) ** 2):.4f}   (paper: ~0.013)")
 
-    # Same filter through the fused Pallas Block-CSR kernel.
+    # Same filter through the Pallas Block-CSR kernel.
     fhat_bsr = filt.apply(y, backend="bsr")[0]
     err = float(jnp.max(jnp.abs(fhat_bsr - fhat)))
     print(f"bsr backend max |delta| vs dense = {err:.2e}")

@@ -2,7 +2,7 @@
 denoising, and semi-supervised classification.
 
 Every routine is built on :class:`repro.filters.GraphFilter`, so it runs
-unchanged on any registered backend — dense, fused Pallas Block-CSR, or the
+unchanged on any registered backend — dense, Pallas Block-CSR, or the
 ``shard_map``-distributed meshes — the paper's point being that the *same*
 Chebyshev recurrence implements all deployment modes. Each takes a
 :class:`~repro.core.graph.SensorGraph`; pass ``backend="..."`` to choose

@@ -4,9 +4,9 @@ Five graph-bound substrates plus one graph-free escape hatch:
 
 * ``dense``      — jnp reference: dense Laplacian matvec, ``lax.scan``
                    recurrence. The parity oracle for everything else.
-* ``bsr``        — Pallas Block-CSR: the fused union-combine kernel when
-                   the VMEM budget allows (one ``pallas_call`` per apply),
-                   the stepwise per-order chain otherwise.
+* ``bsr``        — Pallas Block-CSR: the stepwise chain, one
+                   ``pallas_call`` per order (the matvec fused with the
+                   recurrence step), the eq. 11 combine in XLA.
 * ``halo``       — ``shard_map`` vertex partition, per-order boundary
                    (halo) exchange via ``all_to_all`` — Algorithm 1 on the
                    device mesh.
@@ -258,16 +258,14 @@ class BsrBackend:
     coordinates, most nonzeros first (``graph.degree_order``) where it has
     none — then converts the Laplacian to Block-CSR at the block size
     ``autotune.choose_block_size`` derives from the Laplacian's tile
-    occupancy. ``apply`` picks the fused union-combine kernel when the
-    autotune table says the VMEM working set fits, else chains the
-    stepwise kernel, one grid step per stored tile.
+    occupancy. ``apply`` chains the stepwise kernel
+    (``kernels.ops.cheb_apply_bsr``), one call per order and one grid
+    step per stored tile.
 
     Options: ``block_size`` (prepare; default: derived; multi-shift
-    filters keep 8), ``interpret`` (default:
-    True on the CPU backend only), ``f_tile`` / ``fuse`` overrides, and
-    ``krylov_dtype`` (apply; default f32 — ``"bfloat16"`` halves the
-    kernels' Krylov working set while all combines stay f32, widening
-    the fused-kernel regime in ``autotune.select_tiling``).
+    filters keep 8), ``interpret`` (default: True on the CPU backend
+    only) and ``krylov_dtype`` (apply; default f32 — ``"bfloat16"``
+    rounds the stored Krylov vectors while every step computes in f32).
     """
 
     name = "bsr"
@@ -339,8 +337,6 @@ class BsrBackend:
         *,
         coeffs=None,
         interpret: bool | None = None,
-        f_tile: int | None = None,
-        fuse: bool | None = None,
         krylov_dtype=None,
         **_,
     ):
@@ -355,9 +351,8 @@ class BsrBackend:
             fp, squeeze = self._forward(state, f)
         if isinstance(state, _BsrMultiState):
             # Joint recurrence over the per-shift Block-CSR matvecs (the
-            # jnp reference oracle — the fused/stepwise Pallas kernels are
-            # single-shift; the joint path's inner level reuses them via
-            # cheb_apply's scan only in spirit, not in kernel).
+            # jnp reference oracle — the Pallas step kernel is
+            # single-shift).
             out = chebyshev.cheb_apply_joint(
                 [self._bsr_matvec(m, state.n_pad) for m in state.mats],
                 fp,
@@ -367,23 +362,10 @@ class BsrBackend:
             out = out[:, state.inv]
             return out[:, :, 0] if squeeze else out
         mat = state.mat
-        tiling = autotune.select_tiling(
-            state.n_pad, fp.shape[1], c.shape[0], mat.n_tiles, mat.block_size,
-            fp.dtype, krylov_dtype=kd,
+        out = kops.cheb_apply_bsr(
+            mat.blocks, mat.rows, mat.cols, fp, jnp.asarray(c, fp.dtype),
+            filt.lmax, interpret=interpret, krylov_dtype=kd,
         )
-        if fuse is None:
-            fuse = tiling.fuse
-        ft = f_tile or tiling.f_tile
-        if fuse:
-            out = kops.cheb_apply_bsr_fused(
-                mat.blocks, mat.rows, mat.cols, fp, c, filt.lmax,
-                interpret=interpret, f_tile=ft, krylov_dtype=kd,
-            )
-        else:
-            out = kops.cheb_apply_bsr(
-                mat.blocks, mat.rows, mat.cols, fp, jnp.asarray(c, fp.dtype),
-                filt.lmax, interpret=interpret, f_tile=ft, krylov_dtype=kd,
-            )
         with jax.named_scope("repro.bsr.inverse_perm"):
             out = out[:, state.inv]
         return out[:, :, 0] if squeeze else out

@@ -2,7 +2,7 @@
 
 One filtering primitive, many execution substrates (DESIGN.md Sec. 6): a
 backend packages how ``Phi~ f`` / ``Phi~* a`` are evaluated — dense matmul,
-fused Pallas Block-CSR kernel, or a ``shard_map``-distributed matvec — behind
+the Pallas Block-CSR kernel, or a ``shard_map``-distributed matvec — behind
 a small protocol, so new substrates (GPU sparse, multi-host) drop in by
 registering one class and never touch callers.
 

@@ -1,22 +1,15 @@
-"""Tiling selection for the Chebyshev kernels: autotune-by-table.
+"""Tiling choices for the stepwise Chebyshev kernel, fixed before it runs.
 
 Real autotuning (sweep + timing) is wasteful for a filter that is built
 once and applied millions of times with a handful of distinct shapes.
-Instead we keep a small table of preferred tiles keyed by coarse shape
-buckets, and a deterministic VMEM-budget model decides whether the fused
-kernel fits (DESIGN.md Sec. 6.3).
-
-The decisions this module makes:
+This module answers from the Laplacian and the signal's shape alone
+(DESIGN.md Sec. 6.3):
 
 * ``block_size`` — the Block-CSR tile edge (:func:`choose_block_size`),
   from the Laplacian's own tile occupancy and a cost model, fitted on the
   chip, of the stepwise chain and of what the tiles add to set-up,
-* ``f_tile``  — the F-dimension tile both kernels pipeline over,
-* ``fuse``    — whether the fused union-combine kernel
-  (:func:`repro.kernels.cheb_bsr.cheb_union_pallas`) fits: it keeps the
-  whole (N, f_tile) Krylov state plus the (eta, N, f_tile) accumulators in
-  VMEM, which is only legal while the working set stays under the budget.
-  When it does not fit, callers chain the stepwise kernel instead.
+* ``f_tile``  — the F-dimension tile ``cheb_step_pallas`` pipelines over
+  (:func:`choose_f_tile`), from a small table of preferred tiles.
 """
 
 from __future__ import annotations
@@ -32,18 +25,9 @@ from repro.kernels.ref import stored_tiles, tile_mask
 __all__ = [
     "BLOCK_CANDIDATES",
     "BlockChoice",
-    "Tiling",
     "choose_block_size",
-    "select_tiling",
-    "union_vmem_bytes",
+    "choose_f_tile",
 ]
-
-# Bytes the fused working set may take of the compiler's scoped VMEM
-# limit (16 MiB on v5e), leaving room for the compiler's own scratch.
-# Interpret mode has no real budget but we keep the same decisions so CPU
-# tests exercise the TPU code paths. ``tests/test_tpu_compile.py`` compiles
-# a fused shape near this budget for v5e.
-VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 # Block sizes ``choose_block_size`` weighs, finest first. Each is a whole
 # number of finest slabs, so one occupancy mask at the finest serves all.
@@ -92,21 +76,6 @@ _DEFAULT_LADDER = (256, 128, 64, 32, 16, 8)
 
 
 @dataclasses.dataclass(frozen=True)
-class Tiling:
-    """Resolved kernel launch configuration.
-
-    Attributes:
-      f_tile: F-dimension tile size (divides F).
-      fuse: True when the fused union-combine kernel fits in VMEM.
-      vmem_bytes: working-set estimate of the fused kernel at this tiling.
-    """
-
-    f_tile: int
-    fuse: bool
-    vmem_bytes: int
-
-
-@dataclasses.dataclass(frozen=True)
 class BlockChoice:
     """The Block-CSR block size :func:`choose_block_size` picked.
 
@@ -140,92 +109,13 @@ def _legal_f_tiles(f: int, preferred=_DEFAULT_LADDER) -> list[int]:
     return tiles or [f]
 
 
-def union_vmem_bytes(
-    n: int,
-    f_tile: int,
-    eta: int,
-    n_tiles: int,
-    block: int,
-    dtype=jnp.float32,
-    *,
-    krylov_dtype=jnp.float32,
-) -> int:
-    """VMEM working set of the fused union kernel (bytes).
-
-    Counts each array at its padded VMEM layout (``_vmem_array_bytes``).
-    The pipelined operands — the resident Laplacian tiles, the input
-    tile and the (eta, N, f_tile) output tile — are double-buffered by
-    Pallas, so they count twice; the scratch counts once: two Krylov
-    (ping/pong) buffers in ``krylov_dtype``, the (eta, N, f_tile) f32
-    accumulators and one block-row of ``L @ T``.
-    ``krylov_dtype="bfloat16"`` halves the Krylov term, which is why the
-    bf16 mode raises the fuse threshold in :func:`select_tiling`.
-    """
-    pipelined = (
-        _vmem_array_bytes((n_tiles, block, block), dtype)
-        + _vmem_array_bytes((n, f_tile), dtype)
-        + _vmem_array_bytes((eta, n, f_tile), dtype)
-    )
-    scratch = (
-        2 * _vmem_array_bytes((n, f_tile), krylov_dtype)
-        + _vmem_array_bytes((eta, n, f_tile), jnp.float32)
-        + _vmem_array_bytes((block, f_tile), jnp.float32)
-    )
-    return 2 * pipelined + scratch
-
-
-def select_tiling(
-    n: int,
-    f: int,
-    eta: int,
-    n_tiles: int,
-    block: int,
-    dtype=jnp.float32,
-    vmem_budget: int = VMEM_BUDGET_BYTES,
-    *,
-    krylov_dtype=jnp.float32,
-) -> Tiling:
-    """Pick ``(f_tile, fuse)`` for a Chebyshev union apply.
-
-    Parameters
-    ----------
-    n, f : int
-        Padded signal shape (N, F).
-    eta : int
-        Number of multipliers in the union.
-    n_tiles, block : int
-        Block-CSR operand shape: stored tiles and their edge.
-    dtype : jnp dtype
-        Signal/Laplacian dtype.
-    vmem_budget : int
-        Bytes the fused working set may occupy.
-    krylov_dtype : jnp dtype
-        Krylov-buffer precision inside the fused kernel (bf16 halves
-        that term of the working set, admitting larger fused shapes).
-
-    Returns
-    -------
-    Tiling
-        The widest legal ``f_tile`` (``_legal_f_tiles`` over the table's
-        preferences) whose fused working set fits the budget, with
-        ``fuse=True``; else the widest legal tile with ``fuse=False``.
-    """
-    dt_name = jnp.dtype(dtype).name
-    tiles = _legal_f_tiles(f, _F_TILE_TABLE.get((block, dt_name), _DEFAULT_LADDER))
-    # Widest legal tile that fuses; the widest one when none does.
-    for cand in tiles:
-        bytes_ = union_vmem_bytes(n, cand, eta, n_tiles, block, dtype,
-                                  krylov_dtype=krylov_dtype)
-        if bytes_ <= vmem_budget:
-            return Tiling(f_tile=cand, fuse=True, vmem_bytes=bytes_)
-    return Tiling(
-        f_tile=tiles[0],
-        fuse=False,
-        vmem_bytes=union_vmem_bytes(
-            n, tiles[0], eta, n_tiles, block, dtype,
-            krylov_dtype=krylov_dtype,
-        ),
-    )
+def choose_f_tile(f: int, block: int, dtype=jnp.float32) -> int:
+    """The F tile of the stepwise kernel for a signal of width ``f``: the
+    widest tile the table prefers for ``(block, dtype)`` (the default
+    ladder for a key it lacks) that the TPU lowering accepts
+    (``_legal_f_tiles``)."""
+    preferred = _F_TILE_TABLE.get((block, jnp.dtype(dtype).name), _DEFAULT_LADDER)
+    return _legal_f_tiles(f, preferred)[0]
 
 
 def _order_s(block: int, n_tiles: int) -> float:

@@ -31,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["cheb_step_pallas", "cheb_union_pallas"]
+__all__ = ["cheb_step_pallas"]
 
 # Tile contractions run at full f32 precision (see _cheb_step_kernel).
 _F32 = jax.lax.Precision.HIGHEST
@@ -166,210 +166,3 @@ def cheb_step_pallas(
         interpret=interpret,
         name="cheb_bsr_step",
     )(rows, cols, blocks, t1, t1, t2)
-
-
-# ---------------------------------------------------------------------------
-# Fused union-combine kernel: the whole Chebyshev apply in ONE pallas_call.
-# ---------------------------------------------------------------------------
-
-
-def _cheb_union_kernel(
-    # scalar-prefetch operands
-    rows_ref,  # (n_tiles,) int32, SMEM
-    cols_ref,  # (n_tiles,) int32, SMEM
-    # tensor operands
-    blocks_ref,  # (n_tiles, B, B) — the whole Block-CSR Laplacian
-    f_ref,  # (N, FT)                     input signal tile (= T_0)
-    out_ref,  # (eta, N, FT)              combined outputs, one per multiplier
-    ta_ref,  # (N, FT) VMEM scratch — T_k ping buffer (krylov_dtype)
-    tb_ref,  # (N, FT) VMEM scratch — T_k pong buffer (krylov_dtype)
-    acc_ref,  # (eta, N, FT) f32 VMEM scratch — eq. 11 accumulators
-    row_ref,  # (B, FT) f32 VMEM scratch — one block-row of L @ T_{k-1}
-    *,
-    coeffs: tuple[tuple[float, ...], ...],
-    alpha: float,
-    n_tiles: int,
-    block: int,
-):
-    """Run eq. 9 + eq. 11 entirely in VMEM.
-
-    The recurrence alternates between two (N, FT) scratch buffers; the j-th
-    accumulator picks up ``c_{j,k} * T_k`` inside the same pass over the
-    tiles that produces ``T_k``, so no order's ``T_k`` is ever stored to
-    HBM. The in-place pong write is safe: block-row ``r`` of ``T_{k-2}`` is
-    consumed (aligned read) when row ``r`` of ``T_k`` overwrites it, and
-    the gathered operand is always the *other* buffer (``T_{k-1}``).
-
-    Krylov precision: the ping/pong buffers carry ``krylov_dtype`` (the
-    pallas_call picks the scratch dtype); every step still computes in f32
-    and the accumulators pick up the *pre-rounding* f32 ``T_k`` — only the
-    value the next recurrence step reads back is rounded. With f32 buffers
-    every cast is a no-op; bf16 buffers halve the Krylov VMEM footprint
-    (see ``autotune.union_vmem_bytes``).
-    """
-    order = len(coeffs[0]) - 1
-    f32 = jnp.float32
-
-    def sweep(src_ref, finish):
-        """One pass over the stored tiles: ``L @ src`` a block-row at a
-        time, handed to ``finish(rows, lx)`` on the row's last tile."""
-
-        def tile(t, _):
-            first, last = _row_ends(rows_ref, t, n_tiles)
-
-            @pl.when(first)
-            def _init():
-                row_ref[...] = jnp.zeros_like(row_ref)
-
-            seg = src_ref[pl.ds(cols_ref[t] * block, block), :]
-            row_ref[...] += jnp.dot(
-                blocks_ref[t].astype(f32), seg.astype(f32),
-                preferred_element_type=f32, precision=_F32,
-            )
-
-            @pl.when(last)
-            def _finish():
-                finish(pl.ds(rows_ref[t] * block, block), row_ref[...])
-
-            return 0
-
-        jax.lax.fori_loop(0, n_tiles, tile, 0, unroll=False)
-
-    # ---- k = 0, 1:  T_1 = (L - aI) f / a, accumulators initialised -------
-    def init_row(sl, lx):
-        t0 = f_ref[sl, :].astype(f32)
-        t1 = lx / alpha - t0
-        ta_ref[sl, :] = t1.astype(ta_ref.dtype)
-        for j, c in enumerate(coeffs):
-            acc_ref[j, sl, :] = c[0] * 0.5 * t0 + c[1] * t1
-
-    sweep(f_ref, init_row)
-
-    # ---- k >= 2: ping-pong the recurrence, combine in the same pass ------
-    def make_step(k, src1_ref, src0_ref, dst_ref):
-        # src0 may alias dst: T_k overwrites T_{k-2} row by row (see above).
-        def step_row(sl, lx):
-            t_new = (
-                (2.0 / alpha) * lx
-                - 2.0 * src1_ref[sl, :].astype(f32)
-                - src0_ref[sl, :].astype(f32)
-            )
-            dst_ref[sl, :] = t_new.astype(dst_ref.dtype)
-            for j, c in enumerate(coeffs):
-                acc_ref[j, sl, :] += c[k] * t_new
-
-        sweep(src1_ref, step_row)
-
-    for k in range(2, order + 1):
-        if k == 2:
-            # T_0 still lives in the (read-only) input tile.
-            make_step(k, ta_ref, f_ref, tb_ref)
-        elif k % 2 == 1:
-            make_step(k, tb_ref, ta_ref, ta_ref)
-        else:
-            make_step(k, ta_ref, tb_ref, tb_ref)
-
-    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("coeffs", "lmax", "f_tile", "interpret", "krylov_dtype"),
-)
-def cheb_union_pallas(
-    blocks: jax.Array,
-    rows: jax.Array,
-    cols: jax.Array,
-    f: jax.Array,
-    *,
-    coeffs: tuple[tuple[float, ...], ...],
-    lmax: float,
-    f_tile: int | None = None,
-    interpret: bool = False,
-    krylov_dtype: str = "float32",
-) -> jax.Array:
-    """Full union apply ``Phi~ f`` in a single fused ``pallas_call``.
-
-    Fuses the recurrence (eq. 9) *and* the union combine (eq. 11): the
-    per-signal-tile state — two Krylov buffers plus the ``eta``
-    accumulators — lives in VMEM for the whole apply, so intermediate
-    ``T_k`` tensors are never materialized to HBM (the stepwise
-    ``cheb_apply_bsr`` chain stores each ``T_k`` once per order).
-
-    Requires the working set to fit in VMEM; use
-    :func:`repro.kernels.autotune.select_tiling` to decide between this
-    kernel and the stepwise fallback, and to pick ``f_tile``.
-
-    Parameters
-    ----------
-    blocks, rows, cols : jax.Array
-        Block-CSR Laplacian: (n_tiles, B, B) tiles sorted by block-row,
-        and the (n_tiles,) int32 block-row and block-column of each; every
-        block-row holds at least one tile (``kernels/ref.py``).
-    f : jax.Array
-        (N, F) signal batch, N a multiple of B.
-    coeffs : tuple of tuples
-        Static (eta, M+1) Chebyshev coefficients (hashable: one compile per
-        filter, matching the build-once / apply-many filter lifecycle).
-    lmax : float
-        Static spectrum upper bound.
-    f_tile : int, optional
-        F-dimension tile; defaults to ``min(F, 128)``.
-    interpret : bool
-        Run in Pallas interpret mode (CPU validation path).
-    krylov_dtype : str
-        Static dtype of the two VMEM Krylov (ping/pong) buffers —
-        ``"float32"`` (default) or
-        ``"bfloat16"`` (halves the Krylov working set; the recurrence
-        still computes and accumulates in f32, only the stored ``T_k``
-        round-trips through bf16).
-
-    Returns
-    -------
-    jax.Array
-        (eta, N, F) stacked filter outputs.
-    """
-    n_tiles, b, b2 = blocks.shape
-    assert b == b2, blocks.shape
-    n, fdim = f.shape
-    assert n % b == 0, (f.shape, blocks.shape)
-    eta = len(coeffs)
-    order = len(coeffs[0]) - 1
-    assert order >= 1, "need at least order 1 (two coefficients)"
-    ft = f_tile or min(fdim, 128)
-    assert fdim % ft == 0, (fdim, ft)
-    kdt = jnp.dtype(krylov_dtype)
-
-    kernel = functools.partial(
-        _cheb_union_kernel,
-        coeffs=coeffs,
-        alpha=lmax / 2.0,
-        n_tiles=n_tiles,
-        block=b,
-    )
-
-    grid = (fdim // ft,)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((n_tiles, b, b), lambda fi, rows, cols: (0, 0, 0)),
-                pl.BlockSpec((n, ft), lambda fi, rows, cols: (0, fi)),
-            ],
-            out_specs=pl.BlockSpec((eta, n, ft), lambda fi, rows, cols: (0, 0, fi)),
-            scratch_shapes=[
-                pltpu.VMEM((n, ft), kdt),
-                pltpu.VMEM((n, ft), kdt),
-                pltpu.VMEM((eta, n, ft), jnp.float32),
-                pltpu.VMEM((b, ft), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((eta, n, fdim), f.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
-        ),
-        interpret=interpret,
-        name="cheb_bsr_union",
-    )(rows, cols, blocks, f)

@@ -9,7 +9,7 @@ Two workloads share the static-batching pattern:
   (N,)-signal requests are packed into an (N, F) panel and answered by ONE
   ``GraphFilter.apply`` — the union recurrence is F-blind, so batching
   amortizes the whole Krylov sequence (and, on the ``bsr`` backend, feeds
-  the fused union-combine kernel MXU-shaped panels). This is the serving
+  the Pallas step kernel MXU-shaped panels). This is the serving
   face of the paper's "one recurrence, eta outputs" economics.
 
   The same engine serves *iterative solves* (solve-as-a-service): requests
@@ -159,7 +159,7 @@ class GraphFilterEngine:
     Requests (one (N,) signal each) accumulate until ``panel_width`` are
     pending, then one backend apply answers the whole panel. A fixed panel
     width keeps the jit cache at a single entry (the partial last panel is
-    zero-padded), which is also what the fused Pallas kernel wants: a
+    zero-padded), which is also what the Pallas step kernel wants: a
     stable MXU-aligned F dimension.
 
     Parameters

@@ -3,10 +3,11 @@
 Acceptance contract: every registered backend reachable through
 ``GraphFilter.apply`` matches the dense jnp oracle within 1e-5 (f32) on a
 random sensor graph (grid backend: on its native grid topology), for both
-(N,) and (N, F) signals, and the fused union-combine kernel is one
-``pallas_call`` per apply. Multi-device behaviour of the distributed
-backends is exercised in a forced-8-device subprocess (slow)."""
+(N,) and (N, F) signals, and the ``bsr`` apply runs the Pallas step
+kernel and no other. Multi-device behaviour of the distributed backends is
+exercised in a forced-8-device subprocess (slow)."""
 
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -23,7 +24,7 @@ from repro.filters import (
     backend_is_traceable,
     get_backend,
 )
-from repro.kernels import ops as kops
+from repro.kernels import autotune
 
 REPO = Path(__file__).resolve().parents[1]
 
@@ -212,46 +213,28 @@ def test_graphless_filter_requires_graph(sensor_setting):
         filt.apply(jnp.ones((8,)), backend="dense")
 
 
-def test_fused_union_kernel_is_one_pallas_call(sensor_setting):
-    """The fused kernel issues exactly one pallas_call per apply; the
-    stepwise chain executes one per order (T_k HBM round-trips)."""
+def test_bsr_apply_runs_only_the_step_kernel(sensor_setting):
+    """``GraphFilter.apply(backend="bsr")`` traces exactly two
+    ``pallas_call``s, both the step kernel: the first order and the scan
+    body, which runs the remaining orders."""
     _, filt, f = sensor_setting
-    state = get_backend("bsr").prepare(filt)
-    mat = state.mat
-    fp = jnp.zeros((state.n_pad, 8), f.dtype).at[: state.n].set(
-        f[state.perm])
-
-    fused_jaxpr = jax.make_jaxpr(
-        lambda b, r, c, x: kops.cheb_apply_bsr_fused(
-            b, r, c, x, filt.coeffs, filt.lmax, interpret=True)
-    )(mat.blocks, mat.rows, mat.cols, fp)
-    assert str(fused_jaxpr).count("pallas_call") == 1
-
-    step_jaxpr = jax.make_jaxpr(
-        lambda b, r, c, x: kops.cheb_apply_bsr(
-            b, r, c, x, jnp.asarray(filt.coeffs, x.dtype), filt.lmax,
-            interpret=True)
-    )(mat.blocks, mat.rows, mat.cols, fp)
-    # first-order call + the scan-body call (executed order-1 times).
-    assert str(step_jaxpr).count("pallas_call") >= 2
+    filt.prepare_backend("bsr")  # host-side prepare, outside the trace
+    jaxpr = str(jax.make_jaxpr(lambda x: filt.apply(x, backend="bsr"))(f))
+    assert jaxpr.count("pallas_call") == 2
+    assert re.findall(r"cheb_bsr_\w+", jaxpr) == ["cheb_bsr_step"] * 2
 
 
-def test_fused_matches_stepwise(sensor_setting):
-    _, filt, f = sensor_setting
-    fused = filt.apply(f, backend="bsr", fuse=True)
-    stepwise = filt.apply(f, backend="bsr", fuse=False)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(stepwise),
+def test_bsr_two_f_tiles_match_dense(sensor_setting):
+    """F=256 at block 8 runs the step kernel over two 128-wide F tiles;
+    each tile's columns match the dense oracle."""
+    g, filt, _ = sensor_setting
+    assert autotune.choose_f_tile(256, 8) == 128
+    f = jax.random.normal(jax.random.PRNGKey(7), (g.n_vertices, 256))
+    want = chebyshev.cheb_apply_dense(
+        g.laplacian(), f, jnp.asarray(filt.coeffs, f.dtype), filt.lmax)
+    got = filt.apply(f, backend="bsr", block_size=8)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
-
-
-def test_autotune_falls_back_when_vmem_exceeded():
-    from repro.kernels.autotune import select_tiling
-
-    small = select_tiling(96, 8, 2, 72, 8, jnp.float32)
-    assert small.fuse and small.f_tile == 8
-    huge = select_tiling(2**20, 512, 8, 2**21, 8, jnp.float32,
-                         vmem_budget=1 << 20)
-    assert not huge.fuse
 
 
 def test_backend_state_is_cached(sensor_setting):

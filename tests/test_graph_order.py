@@ -109,7 +109,7 @@ def test_order_span_names_the_method(kron9, tmp_path):
     assert [(a["method"], a["n"]) for a in args] == [("degree", 512), ("spatial", 300)]
 
 
-@pytest.mark.parametrize("opts", [{}, {"block_size": 32, "fuse": False}],
+@pytest.mark.parametrize("opts", [{}, {"block_size": 32}],
                          ids=["chosen", "stepwise_block32"])
 def test_bsr_on_a_kronecker_graph_matches_the_eigendecomposition(kron9, opts):
     """``GraphFilter.apply(backend="bsr")`` at scale 9 without coordinates

@@ -10,8 +10,8 @@ import pytest
 hypothesis = pytest.importorskip("hypothesis")
 st = pytest.importorskip("hypothesis.strategies")
 
-from repro.kernels import ref
-from repro.kernels.cheb_bsr import cheb_step_pallas, cheb_union_pallas
+from repro.kernels import ops, ref
+from repro.kernels.cheb_bsr import cheb_step_pallas
 from repro.core import chebyshev
 
 
@@ -56,23 +56,22 @@ def test_cheb_step_property(n_rows, density, block, f, seed):
     eta=st.integers(1, 3),
     seed=st.integers(0, 2**30),
 )
-def test_cheb_union_fused_property(n_rows, density, order, eta, seed):
-    """Property: the fused union-combine kernel == the jnp union oracle
-    for arbitrary Block-CSR structures, orders, and union widths."""
+def test_cheb_apply_bsr_property(n_rows, density, order, eta, seed):
+    """Property: the stepwise chain ``ops.cheb_apply_bsr`` == the jnp
+    union oracle for arbitrary Block-CSR structures, orders (order 1
+    returns before the scan), and union widths."""
     key = jax.random.PRNGKey(seed)
     kb, kf = jax.random.split(key)
     # 0.3: keep the recurrence numerically tame.
     mat = _random_bsr(kb, n_rows, density, 8, scale=0.3)
     f = jax.random.normal(kf, (mat.n, 8))
-    coeffs = np.asarray(
-        jax.random.normal(kf, (eta, order + 1)), np.float64)
+    coeffs = jnp.asarray(jax.random.normal(kf, (eta, order + 1)), f.dtype)
     lmax = 3.0
-    got = cheb_union_pallas(
-        mat.blocks, mat.rows, mat.cols, f,
-        coeffs=tuple(tuple(float(x) for x in row) for row in coeffs),
-        lmax=lmax, interpret=True)
-    want = chebyshev.cheb_apply(
-        lambda v: ref.bsr_matvec_ref(mat, v), f,
-        jnp.asarray(coeffs, f.dtype), lmax)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-3, atol=2e-3)
+    got = ops.cheb_apply_bsr(mat.blocks, mat.rows, mat.cols, f, coeffs, lmax,
+                             interpret=True)
+    want = np.asarray(chebyshev.cheb_apply(
+        lambda v: ref.bsr_matvec_ref(mat, v), f, coeffs, lmax))
+    # A random block's spectrum leaves [0, lmax], where T_k grows with k:
+    # f32 rounding then scales with the largest output, and so does atol.
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-3,
+                               atol=2e-3 * max(1.0, float(np.max(np.abs(want)))))

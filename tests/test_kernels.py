@@ -111,14 +111,14 @@ def test_stored_tile_step_matches_dense(name):
 @pytest.mark.parametrize("key", sorted(autotune._F_TILE_TABLE))
 def test_select_tiling_returns_lowerable_f_tile(key, f):
     """Pallas TPU takes a block whose last dim is a multiple of 128 or the
-    whole dim: every f_tile select_tiling answers, fused or not, is one."""
+    whole dim: every F tile ``choose_f_tile`` answers is one, and it is
+    the widest such tile the table prefers for the key."""
     block, dtype = key
-    for n in (256, 4096, 16384):
-        for krylov in (jnp.float32, jnp.bfloat16):
-            t = autotune.select_tiling(n, f, 5, 8 * (n // block), block, dtype,
-                                       krylov_dtype=krylov)
-            assert f % t.f_tile == 0, (n, krylov, t)
-            assert t.f_tile % 128 == 0 or t.f_tile == f, (n, krylov, t)
+    t = autotune.choose_f_tile(f, block, dtype)
+    assert f % t == 0, (key, f, t)
+    assert t % 128 == 0 or t == f, (key, f, t)
+    wider = [c for c in autotune._F_TILE_TABLE[key] if c > t]
+    assert all(f % c or (c % 128 and c != f) for c in wider), (key, f, t)
 
 
 def test_full_apply_matches_dense_oracle():

@@ -21,7 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import autotune, ops
-from repro.kernels.cheb_bsr import cheb_step_pallas, cheb_union_pallas
+from repro.kernels.cheb_bsr import cheb_step_pallas
 
 N = 16_384
 F = 128
@@ -73,11 +73,6 @@ def _compile(fn, *specs) -> str:
     return text
 
 
-def _coeffs(eta, order):
-    return tuple(tuple(1.0 / (1 + j + k) for k in range(order + 1))
-                 for j in range(eta))
-
-
 @pytest.mark.parametrize("block,n_tiles", [
     *(pytest.param(b, t, id=str(b)) for b, t in sorted(N_TILES.items())),
     *(pytest.param(b, t, id=f"graph500-{b}") for b, t in sorted(GRAPH500_TILES.items()))])
@@ -95,32 +90,26 @@ def test_step_kernel_compiles(one_chip, block, n_tiles):
 
 @pytest.mark.parametrize("block", autotune.BLOCK_CANDIDATES)
 def test_bf16_tiling_answer_compiles(one_chip, block):
-    """What ``select_tiling`` answers for the smoke apply in bf16 Krylov
-    mode compiles at every block the chooser can return. It once fused at
-    f_tile=8, a block the lowering refuses; the answer now is the stepwise
-    chain at a 128-lane tile."""
+    """The F tile ``choose_f_tile`` answers for the smoke apply compiles in
+    bf16 Krylov mode at every block the chooser can return: a 128-lane
+    tile, as in every cell (the lowering refuses a tile of 8 lanes)."""
     n_tiles = N_TILES[block]
-    tiling = autotune.select_tiling(
-        N, F, ETA, n_tiles, block, krylov_dtype=jnp.bfloat16)
-    assert tiling.f_tile % 128 == 0 or tiling.f_tile == F
-    assert not tiling.fuse
+    f_tile = autotune.choose_f_tile(F, block)
+    assert f_tile == 128
     _compile(
         lambda b, r, c, x, co: ops.cheb_apply_bsr(
-            b, r, c, x, co, LMAX, f_tile=tiling.f_tile,
-            krylov_dtype="bfloat16"),
+            b, r, c, x, co, LMAX, f_tile=f_tile, krylov_dtype="bfloat16"),
         *_bsr_specs(one_chip, block, n_tiles), _spec(one_chip, (N, F)),
         _spec(one_chip, (ETA, ORDER + 1)))
 
 
-def test_fused_kernel_compiles_where_tiling_fuses(one_chip):
-    """A shape ``select_tiling`` fuses in f32, close under its VMEM budget:
-    the compiler must accept what the budget model admits."""
-    n, block, n_tiles = 1024, 128, 16
-    tiling = autotune.select_tiling(n, F, ETA, n_tiles, block)
-    assert tiling.fuse
-    assert tiling.vmem_bytes > autotune.VMEM_BUDGET_BYTES // 2
+@pytest.mark.parametrize("block,n_tiles", [
+    pytest.param(128, N_TILES[128], id="field-128"),
+    pytest.param(256, GRAPH500_TILES[256], id="graph500-256")])
+def test_two_panel_apply_compiles(one_chip, block, n_tiles):
+    """``ops.cheb_apply_bsr`` at F=256, two panels' width, with the F tile
+    it chooses itself, at the block each cell's graph gets."""
     _compile(
-        lambda b, r, c, x: cheb_union_pallas(
-            b, r, c, x, coeffs=_coeffs(ETA, ORDER), lmax=LMAX,
-            f_tile=tiling.f_tile),
-        *_bsr_specs(one_chip, block, n_tiles), _spec(one_chip, (n, F)))
+        lambda b, r, c, x, co: ops.cheb_apply_bsr(b, r, c, x, co, LMAX),
+        *_bsr_specs(one_chip, block, n_tiles), _spec(one_chip, (N, 2 * F)),
+        _spec(one_chip, (ETA, ORDER + 1)))

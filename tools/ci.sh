@@ -3,9 +3,9 @@
 # smoke-run the quickstart example so example drift is caught.
 #
 # All Pallas paths run with interpret=True on the CPU (the bsr backend
-# chooses it there and nowhere else), so the whole matrix — including the fused union-combine
-# kernel and the multi-device subprocess tests (forced host devices) — is
-# exercised on a plain CPU runner. Collection errors fail the run
+# chooses it there and nowhere else), so the whole matrix — including the
+# Pallas step kernel and the multi-device subprocess tests (forced host
+# devices) — is exercised on a plain CPU runner. Collection errors fail the run
 # (pytest exits non-zero on them; --co smoke-checks first for clarity).
 #
 # Lanes (CI_LANE env var, default "fast"):
@@ -34,7 +34,7 @@ python -m pip install -r requirements-dev.txt
 FORMAT_PATHS=(src/repro/stream src/repro/serve src/repro/dynamic
               src/repro/filters src/repro/solvers
               src/repro/train src/repro/runtime
-              benchmarks/loadgen.py tools/bench_check.py)
+              benchmarks/loadgen.py)
 if python -m ruff --version >/dev/null 2>&1; then
   python -m ruff check .
   python -m ruff format --check "${FORMAT_PATHS[@]}"
@@ -57,7 +57,7 @@ case "$LANE" in
   fast)
     python -m pytest -x -q -m "not slow" "${TIMEOUT_ARGS[@]}"
     # Serving-path smoke: the load generator must drive both engines end
-    # to end on a small trace (full-size runs live in the perf-gate job).
+    # to end on a small trace.
     PYTHONPATH=src python -m benchmarks.loadgen --streams 200 --seconds 2 \
       --rate 200
     # Decentralized-training smoke: 3 steps of the bucketed-gossip
@@ -68,7 +68,7 @@ case "$LANE" in
       --grad-sync gossip
     # Churn smoke: a small mobile-sensor scenario streamed with per-frame
     # GraphDeltas must stay exact vs a from-scratch dense refilter on the
-    # evolved graph (full-scale numbers live in tab_churn / the perf gate).
+    # evolved graph (full-scale numbers live in tab_churn).
     PYTHONPATH=src python - <<'PY'
 import numpy as np
 from repro.core.chebyshev import cheb_apply_dense
